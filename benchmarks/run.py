@@ -62,10 +62,15 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="fast CI subset (quick configs, no DES-heavy "
                          "modules)")
+    ap.add_argument("--interpret", action="store_true",
+                    help="run the Pallas kernels of kernels_bench in "
+                         "interpret mode (off the chip only)")
     args = ap.parse_args()
 
     if args.smoke and args.full:
         ap.error("--smoke and --full are mutually exclusive")
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     modules = SMOKE_MODULES if args.smoke else MODULES
     if not args.json:
         print("name,us_per_call,derived")
@@ -76,7 +81,9 @@ def main() -> None:
             continue
         try:
             mod = __import__(mod_name, fromlist=["run"])
-            rows = mod.run(quick=not args.full)
+            kw = ({"interpret": True} if args.interpret
+                  and mod_name == "benchmarks.kernels_bench" else {})
+            rows = mod.run(quick=not args.full, **kw)
             for r in rows:
                 if args.json:
                     print(json.dumps(r), flush=True)

@@ -200,7 +200,6 @@ def fit_fastsim_params(runs: Sequence[Tuple["HPLConfig", float]],
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
     from repro.train.optimizer import adamw_init, adamw_update
     from .fastsim import FastSimParams, _f64_params, simulate_time_traced
 
@@ -218,7 +217,7 @@ def fit_fastsim_params(runs: Sequence[Tuple["HPLConfig", float]],
                 for (cfg, _), lm in zip(runs, logt_meas)]
         return sum(e * e for e in errs) / len(runs)
 
-    with enable_x64(True):
+    with jax.enable_x64(True):
         vg = jax.jit(jax.value_and_grad(loss_fn))
         theta = jnp.asarray([math.log(base[f]) for f in fields],
                             jnp.float64)

@@ -36,7 +36,6 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.obs.metrics import RATIO_BUCKETS, get_global_metrics
 
@@ -303,9 +302,9 @@ def _sim_core(N, nb, P, Q, prm: FastSimParams,
 # trailing/leading scenario axis is embarrassingly parallel (every lane
 # is an independent recurrence), so when more than one local device is
 # available the padded lane axis can be split across them.  Off by
-# default; the single-device (or indivisible-batch) fallback takes the
-# exact pre-sharding code path, so results are bitwise-identical to an
-# unsharded dispatch by construction.
+# default.  While it is on, lane batches pad to a multiple of the device
+# count, so every batched dispatch splits over every device; with one
+# device the dispatch is the unsharded one, bitwise.
 _LANE_SHARDING = False
 
 
@@ -334,17 +333,25 @@ def shard_device_count() -> int:
     return len(jax.devices())
 
 
+def _lane_device_count() -> int:
+    """Devices a batched dispatch splits its lanes over right now."""
+    return shard_device_count() if _LANE_SHARDING else 1
+
+
 def _shard_lanes(n_lanes: int, *trees):
     """Place ``(B,)``-leading pytrees across local devices along the
     lane axis.  Returns ``(trees, sharded)``; identity (and False) when
-    sharding is off, only one device exists, or the padded batch does
-    not divide the device count — the single-device fallback that keeps
-    results bitwise-identical to the unsharded path."""
+    sharding is off or only one device exists.  ``_pad_lanes`` sized
+    the batch, so a lane count that does not divide the device count is
+    a bug and raises."""
     if not _LANE_SHARDING:
         return trees, False
     devs = jax.devices()
-    if len(devs) <= 1 or n_lanes % len(devs):
+    if len(devs) <= 1:
         return trees, False
+    if n_lanes % len(devs):
+        raise ValueError(f"sharded dispatch of {n_lanes} lanes over "
+                         f"{len(devs)} devices: pad with _pad_lanes")
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
     mesh = Mesh(np.asarray(devs), ("lanes",))
     sharding = NamedSharding(mesh, PartitionSpec("lanes"))
@@ -436,14 +443,19 @@ def _stack_params(prm_list: Sequence[FastSimParams],
         for n in _PARAM_FIELDS})
 
 
-def _pad_pow2(idxs: List[int]) -> List[int]:
+def _pad_lanes(idxs: List[int]) -> List[int]:
+    """Pad a lane batch to a power of two (so repeat sweeps of any size
+    reuse the compile cache), rounded up to a multiple of the devices a
+    sharded dispatch splits it over."""
     pad = 1 << (len(idxs) - 1).bit_length()
+    n_dev = _lane_device_count()
+    pad = -(-pad // n_dev) * n_dev
     return idxs + [idxs[-1]] * (pad - len(idxs))
 
 
 def simulate_time_traced(cfg: HPLConfig, prm: FastSimParams):
     """Differentiable scalar HPL time for traced ``prm`` leaves (call
-    under ``jax.experimental.enable_x64``; config stays concrete).  This
+    under ``jax.enable_x64(True)``; config stays concrete).  This
     is the autodiff surface used by ``calibrate.fit_fastsim_params``."""
     return _sim_core_scalar(np.int64(cfg.N), np.int64(cfg.nb),
                             np.int64(cfg.P), np.int64(cfg.Q), prm,
@@ -456,7 +468,7 @@ def _result(cfg: HPLConfig, t: float) -> dict:
 
 
 def simulate_hpl_fast(cfg: HPLConfig, prm: FastSimParams) -> dict:
-    with enable_x64(True):
+    with jax.enable_x64(True):
         t = _run_single(cfg, prm)
     return _result(cfg, t)
 
@@ -506,13 +518,13 @@ def sweep_hpl(configs: Configs, params: Params, *,
     times = np.empty(len(cfg_list), np.float64)
     mixed: Dict[Tuple[int, int, int], List[int]] = {}
     m = get_global_metrics()
-    with enable_x64(True):
+    with jax.enable_x64(True):
         for (N, nb, P, Q), idxs in by_cfg.items():
             key = bucket_key(cfg_list[idxs[0]])
             if len(idxs) == 1:
                 mixed.setdefault(key, []).append(idxs[0])
                 continue
-            lanes = _pad_pow2(idxs)
+            lanes = _pad_lanes(idxs)
             fn = _compiled(*key, "params")
             (stacked,), sharded = _shard_lanes(
                 len(lanes), _stack_params(prm_list, lanes))
@@ -530,7 +542,7 @@ def sweep_hpl(configs: Configs, params: Params, *,
                 times[idxs[0]] = _run_single(cfg_list[idxs[0]],
                                              prm_list[idxs[0]])
                 continue
-            lanes = _pad_pow2(idxs)
+            lanes = _pad_lanes(idxs)
             geom = np.asarray([[cfg_list[i].N, cfg_list[i].nb,
                                 cfg_list[i].P, cfg_list[i].Q]
                                for i in lanes], np.int64)
@@ -563,12 +575,12 @@ def _sweep_forced_bucket(cfg_list: Sequence[HPLConfig],
                 f"sweep_hpl: config (N={cfg.N}, nb={cfg.nb}, P={cfg.P}, "
                 f"Q={cfg.Q}) exceeds forced bucket "
                 f"({n_panels_max}, {P_max}, {Q_max})")
-    lanes = _pad_pow2(list(range(len(cfg_list))))
+    lanes = _pad_lanes(list(range(len(cfg_list))))
     geom = np.asarray([[cfg_list[i].N, cfg_list[i].nb,
                         cfg_list[i].P, cfg_list[i].Q]
                        for i in lanes], np.int64)
     m = get_global_metrics()
-    with enable_x64(True):
+    with jax.enable_x64(True):
         fn = _compiled(n_panels_max, P_max, Q_max, "batch")
         args, sharded = _shard_lanes(
             len(lanes), geom[:, 0], geom[:, 1], geom[:, 2], geom[:, 3],

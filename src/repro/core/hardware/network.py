@@ -13,7 +13,8 @@ completion time is re-predicted.  Contention (the paper's §V finding that a
 the shared-link allocation.
 
 The max-min allocation also exists as a vectorized JAX/Pallas kernel
-(``repro.kernels.maxmin_fair``) used by the fast exascale path.
+(``repro.kernels.maxmin_fair``); nothing in the simulator calls it yet —
+this module's host loop is the allocation every DES run uses.
 """
 from __future__ import annotations
 

@@ -4,9 +4,9 @@ TPU-native design (not a CUDA port — DESIGN.md §2):
   * grid (B, G, NQ, NK) with the KV axis innermost and *arbitrary*
     dimension semantics: the online-softmax state (m, l, acc) lives in
     VMEM scratch and is carried across NK grid steps;
-  * q block (bq, R, hd) is flattened to (bq*R, hd) so the MXU sees a
-    (bq*R, hd) x (hd, bk) matmul — R query heads per KV group ride along
-    the sublane dim for free;
+  * q is laid out head-major as (B, G, Sq*R, hd), so a q block is a
+    (bq*R, hd) tile and the MXU sees a (bq*R, hd) x (hd, bk) matmul — R
+    query heads per KV group ride along the sublane dim for free;
   * fully-masked causal blocks are skipped with @pl.when (real FLOP
     savings on TPU — the XLA fallback in models/layers.py can only mask);
   * block sizes default to 128/128: MXU-aligned (multiples of 128) and
@@ -22,14 +22,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from .._compat import CompilerParams as _CompilerParams
 
 
 NEG_INF = -1e30
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                  bq: int, bk: int, causal: bool, scale: float,
+                  bq: int, bk: int, r: int, causal: bool, scale: float,
                   n_k_blocks: int):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
@@ -45,19 +44,18 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
     @pl.when(run)
     def _body():
-        q = q_ref[0, :, 0, :, :]                       # (bq, R, hd)
-        r, hd = q.shape[1], q.shape[2]
-        qf = (q * scale).reshape(bq * r, hd)
-        k = k_ref[0, :, 0, :]                          # (bk, hd)
-        v = v_ref[0, :, 0, :]
+        qf = q_ref[0, 0] * scale                       # (bq*R, hd)
+        k = k_ref[0, 0]                                # (bk, hd)
+        v = v_ref[0, 0]
         s = jax.lax.dot_general(qf, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if causal:
-            qpos = qi * bq + jax.lax.broadcasted_iota(
-                jnp.int32, (bq * r, bk), 0) // r
-            kpos = ki * bk + jax.lax.broadcasted_iota(
+            # row i is query position qi*bq + i // R; "kpos <= qpos" is
+            # tested as (kpos - qi*bq) * R <= i, which needs no division
+            row = jax.lax.broadcasted_iota(jnp.int32, (bq * r, bk), 0)
+            kd = ki * bk - qi * bq + jax.lax.broadcasted_iota(
                 jnp.int32, (bq * r, bk), 1)
-            s = jnp.where(kpos <= qpos, s, NEG_INF)
+            s = jnp.where(kd * r <= row, s, NEG_INF)
         m_prev = m_ref[...]
         l_prev = l_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -73,10 +71,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
     @pl.when(ki == n_k_blocks - 1)
     def _finish():
-        r = q_ref.shape[3]
-        hd = q_ref.shape[4]
         out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :, :] = out.reshape(bq, r, hd).astype(o_ref.dtype)
+        o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
 def flash_attention_fwd(q, k, v, *, causal: bool = True, bq: int = 128,
@@ -89,30 +85,35 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, bq: int = 128,
     assert sq % bq == 0 and sk % bk == 0, (sq, bq, sk, bk)
     nq, nk = sq // bq, sk // bk
     scale = 1.0 / math.sqrt(hd)
-    kernel = functools.partial(_flash_kernel, bq=bq, bk=bk, causal=causal,
-                               scale=scale, n_k_blocks=nk)
-    grid = (b, g, nq, nk)
-    return pl.pallas_call(
+    kernel = functools.partial(_flash_kernel, bq=bq, bk=bk, r=r,
+                               causal=causal, scale=scale, n_k_blocks=nk)
+    # head-major layout: every block's last two dims are (rows, hd), so
+    # they tile as the TPU requires (rows a multiple of 8, hd the full dim)
+    qg = q.transpose(0, 2, 1, 3, 4).reshape(b, g, sq * r, hd)
+    kg = k.transpose(0, 2, 1, 3)
+    vg = v.transpose(0, 2, 1, 3)
+    out = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(b, g, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, r, hd),
-                         lambda bi, gi, qi, ki: (bi, qi, gi, 0, 0)),
-            pl.BlockSpec((1, bk, 1, hd),
-                         lambda bi, gi, qi, ki: (bi, ki, gi, 0)),
-            pl.BlockSpec((1, bk, 1, hd),
-                         lambda bi, gi, qi, ki: (bi, ki, gi, 0)),
+            pl.BlockSpec((1, 1, bq * r, hd),
+                         lambda bi, gi, qi, ki: (bi, gi, qi, 0)),
+            pl.BlockSpec((1, 1, bk, hd),
+                         lambda bi, gi, qi, ki: (bi, gi, ki, 0)),
+            pl.BlockSpec((1, 1, bk, hd),
+                         lambda bi, gi, qi, ki: (bi, gi, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, r, hd),
-                               lambda bi, gi, qi, ki: (bi, qi, gi, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sq, g, r, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, bq * r, hd),
+                               lambda bi, gi, qi, ki: (bi, gi, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, g, sq * r, hd), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq * r, 1), jnp.float32),    # m
             pltpu.VMEM((bq * r, 1), jnp.float32),    # l
             pltpu.VMEM((bq * r, hd), jnp.float32),   # acc
         ],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
-    )(q, k, v)
+    )(qg, kg, vg)
+    return out.reshape(b, g, sq, r, hd).transpose(0, 2, 1, 3, 4)

@@ -1,7 +1,8 @@
 """jit'd public wrapper for the flash-attention kernel.
 
-On non-TPU backends the kernel runs in interpret mode (Python execution of
-the kernel body — correctness only); on TPU it compiles via Mosaic.
+The kernel compiles via Mosaic, so it runs on a TPU.  ``interpret=True``
+executes the kernel body in Python instead (correctness checks off the
+chip); it is never chosen on the caller's behalf.
 """
 from __future__ import annotations
 
@@ -12,13 +13,9 @@ import jax
 from .kernel import flash_attention_fwd
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-@partial(jax.jit, static_argnames=("causal", "bq", "bk"))
+@partial(jax.jit, static_argnames=("causal", "bq", "bk", "interpret"))
 def flash_attention(q, k, v, causal: bool = True, bq: int = 128,
-                    bk: int = 128):
+                    bk: int = 128, interpret: bool = False):
     """q: (B, Sq, G, R, hd); k, v: (B, Sk, G, hd) -> (B, Sq, G, R, hd)."""
     return flash_attention_fwd(q, k, v, causal=causal, bq=bq, bk=bk,
-                               interpret=not _on_tpu())
+                               interpret=interpret)

@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from .._compat import CompilerParams as _CompilerParams
 
 
 INF = 3.4e38
@@ -27,9 +26,11 @@ def _minrows_kernel(adj_ref, vals_ref, out_ref, acc_ref, *, n_l_blocks):
     def _init():
         acc_ref[...] = jnp.full_like(acc_ref, INF)
 
-    adj = adj_ref[...]                       # (bf, bl) int8
-    vals = vals_ref[...]                     # (1, bl) f32
-    masked = jnp.where(adj > 0, vals, INF)   # broadcast over rows
+    # widen int8 -> int32 and broadcast vals before the select: a mask
+    # compared in int8 layout cannot be relaid out against (1, bl) rows
+    adj = adj_ref[...].astype(jnp.int32)     # (bf, bl)
+    vals = jnp.broadcast_to(vals_ref[...], adj.shape)
+    masked = jnp.where(adj > 0, vals, INF)
     acc_ref[...] = jnp.minimum(acc_ref[...],
                                jnp.min(masked, axis=1, keepdims=True))
 
@@ -59,7 +60,7 @@ def masked_min_rows(adj, vals, *, bf: int = 256, bl: int = 256,
         out_shape=jax.ShapeDtypeStruct((F, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bf, 1), jnp.float32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(adj.astype(jnp.int8), vals2)
     return out[:, 0]

@@ -10,20 +10,22 @@ from .kernel import masked_min_rows, INF
 from .ref import waterfill_ref
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-@partial(jax.jit, static_argnames=("max_iters", "use_kernel"))
-def waterfill(adj, caps, max_iters: int = 64, use_kernel: bool = True):
-    """Max-min fair rates via progressive filling; the per-iteration
-    masked row-min runs through the Pallas kernel."""
+@partial(jax.jit, static_argnames=("max_iters", "use_kernel", "interpret"))
+def waterfill(adj, caps, max_iters: int = 64, use_kernel: bool = True,
+              interpret: bool = False):
+    """Max-min fair rates via progressive filling; with ``use_kernel`` the
+    per-iteration masked row-min runs through the Pallas kernel, which
+    needs F % 8 == 0 and L % 128 == 0 (``interpret=True`` runs it in
+    Python, off the chip)."""
     F, L = adj.shape
     adjf = adj.astype(jnp.float32)
-    interpret = not _on_tpu()
+    if use_kernel and (F % 8 or L % 128):
+        raise ValueError(f"waterfill kernel needs F % 8 == 0 and "
+                         f"L % 128 == 0, got F={F}, L={L}; pass "
+                         "use_kernel=False for the jnp path")
 
     def minrows(share):
-        if use_kernel and F % 8 == 0 and L % 128 == 0:
+        if use_kernel:
             return masked_min_rows(adj, share, bf=min(256, F),
                                    bl=min(256, L), interpret=interpret)
         return jnp.min(jnp.where(adj > 0, share[None, :], INF), axis=1)

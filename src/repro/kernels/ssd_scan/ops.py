@@ -8,8 +8,8 @@ import jax
 from .kernel import ssd_scan
 
 
-@partial(jax.jit, static_argnames=("chunk",))
-def ssd(xh, dt, A, Bh, Ch, chunk: int = 256):
-    """See kernel.ssd_scan.  Interpret mode off-TPU."""
-    return ssd_scan(xh, dt, A, Bh, Ch, chunk,
-                    interpret=jax.default_backend() != "tpu")
+@partial(jax.jit, static_argnames=("chunk", "interpret"))
+def ssd(xh, dt, A, Bh, Ch, chunk: int = 256, interpret: bool = False):
+    """See kernel.ssd_scan.  Compiles for the TPU; ``interpret=True``
+    runs the kernel body in Python (off-chip correctness checks)."""
+    return ssd_scan(xh, dt, A, Bh, Ch, chunk, interpret=interpret)

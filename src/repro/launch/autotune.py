@@ -14,6 +14,9 @@ land in experiments/autotune/ and the winner is printed with its full
 term breakdown.
 """
 import os
+# a compile rehearsal on 512 host CPU devices: pinned to the CPU before
+# jax is imported, so it never takes a TPU on a machine that has one
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
 
 import argparse

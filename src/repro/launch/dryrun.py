@@ -1,11 +1,16 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above MUST run before any jax import — jax locks the device
-count on first init.  This proves the distribution config is coherent
-without real hardware: a sharding mismatch, compile-time OOM, or an
-unsupported collective is a bug in the framework, surfaced here.
+The lines above MUST run before any jax import — jax locks the platform
+and the device count on first init.  The dry run is a compile rehearsal
+on 512 host CPU devices; pinned to the CPU it never takes a TPU on a
+machine that has one (and the per-cell children of ``--all`` inherit the
+pin, so they never fight over it).  This proves the distribution config
+is coherent without real hardware: a sharding mismatch, compile-time
+OOM, or an unsupported collective is a bug in the framework, surfaced
+here.
 
 Usage:
     python -m repro.launch.dryrun --arch qwen2-0.5b --shape train_4k

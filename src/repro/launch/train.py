@@ -9,6 +9,7 @@ dry-run path); real execution is for reduced configs (--smoke).
 from __future__ import annotations
 
 import argparse
+import os
 
 
 def main():
@@ -27,9 +28,12 @@ def main():
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    # reduced-config runs and dry-run compiles both target the host CPU:
+    # pinned before jax is imported, so neither this process nor the
+    # dry-run child it starts takes (or waits on) a TPU
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
     if args.dryrun:
-        import os
         import subprocess
         import sys
         cmd = [sys.executable, "-m", "repro.launch.dryrun", "--arch",

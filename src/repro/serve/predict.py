@@ -44,7 +44,9 @@ above is the default):
   * transient backend errors (``RuntimeError``/``OSError`` from a sweep
     dispatch) are retried with exponential backoff (``retries``,
     ``backoff_s``); scenario errors (``ValueError``/``KeyError``) never
-    are.
+    are, and neither are XLA's own errors (``jax.errors.JaxRuntimeError``:
+    a refused compile, a device out of memory), which surface on the
+    first attempt instead of after minutes of recompiles.
   * ``predict_batch(reqs, isolate_errors=True)`` captures per-request
     resolution errors into ``{"status": "error", ...}`` response
     entries instead of rejecting the wave; failed requests are never
@@ -62,8 +64,8 @@ Production throughput (all opt-in; DESIGN.md §20):
     (``timeout_s``) requests and error/degraded results are never
     cached.
   * ``PredictionService(shard=True)`` splits each family sweep's padded
-    lane axis across local devices; with one device (or an indivisible
-    batch) it falls back to the exact unsharded code path.
+    lane axis across local devices (the batch pads to a multiple of the
+    device count); with one device it is the exact unsharded code path.
   * ``svc.warm(workloads, platforms, count=...)`` (or ``python -m
     repro.serve warm``) precompiles the sweep buckets a traffic mix
     will need, so the first real wave pays zero compiles — verified by
@@ -93,6 +95,8 @@ import dataclasses
 import time
 import weakref
 from typing import Any, Dict, List, Mapping, Optional, Sequence
+
+import jax
 
 from repro.core.apps.hpl import HPLConfig
 from repro.core.engine import SimWallDeadline
@@ -168,7 +172,9 @@ class PredictionService:
     queue one batched sweep per workload family per wave."""
 
     #: exception types a sweep dispatch may raise transiently (backend
-    #: hiccups); scenario errors (ValueError/KeyError) are never retried
+    #: hiccups); scenario errors (ValueError/KeyError) are never retried,
+    #: nor is an XLA error, though it subclasses RuntimeError (see
+    #: ``_dispatch``)
     TRANSIENT = (RuntimeError, OSError)
 
     def __init__(self, max_batch: int = 256, max_des_ranks: int = 1024,
@@ -195,8 +201,8 @@ class PredictionService:
         #: (share one ResultCache across services to share results).
         self.cache = as_result_cache(cache)
         #: off by default — True shards each family sweep's padded lane
-        #: axis across local devices (single-device fallback is bitwise-
-        #: identical to the unsharded path)
+        #: axis across local devices (with one device it is the
+        #: unsharded path, bitwise)
         self.shard = bool(shard)
         #: (workload, params, platform, faults) -> (wl, plat, model);
         #: name-level resolutions are pure, so repeat traffic skips the
@@ -315,8 +321,11 @@ class PredictionService:
 
     def _dispatch(self, model_cls, reqs: List[WorkloadRequest]) -> List[dict]:
         """One batched sweep per family, with bounded retry + exponential
-        backoff for transient backend errors.  With ``shard=True`` the
-        sweep's padded lane axis is split across local devices."""
+        backoff for transient backend errors.  A ``JaxRuntimeError`` (a
+        compile the device refused, device memory exhausted) is raised
+        at once: retrying recompiles for minutes and fails the same way.
+        With ``shard=True`` the sweep's padded lane axis is split across
+        local devices."""
         models = [r._bound[2] for r in reqs]
         delay = self.backoff_s
         for attempt in range(self.retries + 1):
@@ -325,8 +334,9 @@ class PredictionService:
                     with lane_sharding(True):
                         return model_cls.sweep_models(models)
                 return model_cls.sweep_models(models)
-            except self.TRANSIENT:
-                if attempt == self.retries:
+            except self.TRANSIENT as exc:
+                if (attempt == self.retries
+                        or isinstance(exc, jax.errors.JaxRuntimeError)):
                     raise
                 self.stats["retries"] += 1
                 self.metrics.counter("serve.retries").inc()

@@ -20,11 +20,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
-
-# jax 0.4.x shard_map has no varying-axis type system; pvary is identity
-_pvary = getattr(lax, "pvary", lambda x, axis: x)
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 
 def pipeline_forward(layer_fn: Callable, stage_params, x, *,
@@ -68,8 +64,8 @@ def pipeline_forward(layer_fn: Callable, stage_params, x, *,
 
         buf0 = jnp.zeros((mb,) + x_local.shape[1:], x_local.dtype)
         # initial carry must already be pod-varying for scan type stability
-        buf0 = _pvary(buf0, axis)
-        outs0 = _pvary(outs0, axis)
+        buf0 = lax.pcast(buf0, axis, to="varying")
+        outs0 = lax.pcast(outs0, axis, to="varying")
         (_, outs), _ = lax.scan(tick, (buf0, outs0),
                                 jnp.arange(n_ticks))
         # outs on the LAST stage holds the final microbatch outputs;
@@ -79,9 +75,10 @@ def pipeline_forward(layer_fn: Callable, stage_params, x, *,
         return outs[:n_micro].reshape(x_local.shape)
 
     pspec_params = jax.tree.map(lambda _: P(axis), stage_params)
-    return shard_map(per_pod, mesh=mesh,
-                     in_specs=(pspec_params, P()),
-                     out_specs=P())(stage_params, x)
+    fwd = jax.shard_map(per_pod, mesh=mesh,
+                        in_specs=(pspec_params, P()), out_specs=P())
+    with jax.set_mesh(mesh):
+        return jax.jit(fwd)(stage_params, x)
 
 
 def pipeline_bubble_fraction(n_micro: int, n_stages: int) -> float:

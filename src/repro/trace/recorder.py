@@ -121,11 +121,16 @@ class TraceRecorder:
         self.coll_members: Dict[Any, List[int]] = {}   # coll key -> [sid]
         self._msg_by_event: Dict[int, Message] = {}    # id(Event) -> Message
         self._coll_depth: Dict[int, int] = {}          # rank -> open colls
+        self._t_end = 0.0                              # latest span end
 
     # ------------------------------------------------------------- state
     @property
     def makespan(self) -> float:
-        return self.engine.now
+        """End of the last recorded span or instant.  The engine clock
+        can run past it on a delivery no rank waits for (a long-broadcast
+        forward that lands after every rank has finished), which is not
+        part of the traced program's time."""
+        return self._t_end
 
     @property
     def now(self) -> float:
@@ -141,10 +146,10 @@ class TraceRecorder:
                  args: Optional[Dict[str, Any]] = None) -> int:
         """Record a finished span [t0, t1] (t1 defaults to sim-now)."""
         sid = len(self.spans)
-        self.spans.append(Span(sid, rank, cat, name, t0,
-                               self.engine.now if t1 is None else t1,
-                               coll=coll, nested=nested, deps=deps,
-                               args=args))
+        t1 = self.engine.now if t1 is None else t1
+        self._t_end = max(self._t_end, t1)
+        self.spans.append(Span(sid, rank, cat, name, t0, t1, coll=coll,
+                               nested=nested, deps=deps, args=args))
         return sid
 
     def compute(self, rank: int, name: str, dur: float,
@@ -157,6 +162,7 @@ class TraceRecorder:
 
     def instant(self, rank: int, name: str,
                 args: Optional[Dict[str, Any]] = None):
+        self._t_end = max(self._t_end, self.engine.now)
         self.instants.append((rank, name, self.engine.now, args))
 
     # ------------------------------------------------------- collectives

@@ -31,9 +31,8 @@ from typing import Dict, List, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
-from repro.core.fastsim import _pad_pow2, _record_shard, _shard_lanes
+from repro.core.fastsim import _pad_lanes, _record_shard, _shard_lanes
 from repro.obs.metrics import RATIO_BUCKETS, get_global_metrics
 
 
@@ -127,7 +126,7 @@ def _compiled():
 
 def step_time_traced(p: StepParams):
     """Differentiable scalar step time for traced ``p`` leaves (call
-    under ``jax.experimental.enable_x64``) — the autodiff surface for
+    under ``jax.enable_x64(True)``) — the autodiff surface for
     gradient calibration of step parameters."""
     return _step_core(p)
 
@@ -157,9 +156,9 @@ def sweep_step(params_list: Sequence[StepParams]) -> List[Dict]:
     prm_list = [_f64_step_params(p) for p in params_list]
     if not prm_list:
         return []
-    lanes = _pad_pow2(list(range(len(prm_list))))
+    lanes = _pad_lanes(list(range(len(prm_list))))
     m = get_global_metrics()
-    with enable_x64(True):
+    with jax.enable_x64(True):
         fn = _compiled()
         (stacked,), sharded = _shard_lanes(
             len(lanes), _stack_step_params(prm_list, lanes))

@@ -10,7 +10,7 @@ import pytest
 pytest.importorskip("hypothesis")
 
 import hypothesis.strategies as st
-from hypothesis import given, settings
+from hypothesis import assume, given, settings
 
 from repro.campaign import Budget, CampaignSpec, PlatformSelector
 from repro.faults import FaultSpec
@@ -61,10 +61,14 @@ def campaign_specs(draw):
     axes = draw(st.dictionaries(
         names, st.lists(scalars, min_size=1, max_size=4, unique=True),
         max_size=3))
+    workloads = draw(st.lists(workload_specs(), max_size=3))
+    platforms = draw(st.lists(selectors(), min_size=1, max_size=3))
+    # registry selectors need a workload to run (CampaignSpec rejects it)
+    assume(workloads or all(s.kind != "registry" for s in platforms))
     return CampaignSpec.make(
         draw(names),
-        workloads=draw(st.lists(workload_specs(), max_size=3)),
-        platforms=draw(st.lists(selectors(), min_size=1, max_size=3)),
+        workloads=workloads,
+        platforms=platforms,
         axes=axes,
         faults=draw(st.lists(fault_specs(), min_size=1, max_size=3)),
         seeds=draw(st.lists(st.integers(0, 2**31), min_size=1,

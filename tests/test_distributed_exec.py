@@ -27,6 +27,7 @@ os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'
 import dataclasses
 import jax, jax.numpy as jnp
 from repro.configs import get_config, reduced
+from repro.launch.mesh import make_local_mesh
 from repro.models import build_model
 from repro.models.api import abstract_state
 from repro.sharding.specs import make_rules, tree_shardings, use_rules
@@ -35,7 +36,7 @@ from repro.train.step import make_train_state, make_train_step, state_specs
 cfg = dataclasses.replace(reduced(get_config('granite-34b')),
                           n_heads=8, n_kv_heads=1, head_dim=32, d_model=128,
                           d_ff=256, num_layers=2)
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = make_local_mesh(data=2, model=4)
 rules = make_rules(cfg, mode='train', tp_size=4, dp_size=2, global_batch=4)
 model = build_model(cfg)
 with mesh, use_rules(rules, mesh):
@@ -68,6 +69,7 @@ import dataclasses
 import jax, jax.numpy as jnp
 from repro.checkpoint import save_checkpoint, restore_checkpoint, latest_step
 from repro.configs import get_config, reduced
+from repro.launch.mesh import make_local_mesh
 from repro.models import build_model
 from repro.sharding.specs import make_rules, tree_shardings, use_rules
 from repro.train.step import make_train_state, make_train_step, state_specs
@@ -76,7 +78,7 @@ MESH = %s
 cfg = dataclasses.replace(reduced(get_config('stablelm-3b')),
                           n_heads=8, n_kv_heads=8, head_dim=16, d_model=128,
                           d_ff=256, num_layers=2)
-mesh = jax.make_mesh(MESH, ('data', 'model'))
+mesh = make_local_mesh(*MESH)
 rules = make_rules(cfg, mode='train', tp_size=MESH[1], dp_size=MESH[0],
                    global_batch=4)
 model = build_model(cfg)

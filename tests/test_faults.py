@@ -369,6 +369,30 @@ def test_service_retries_transient_backend_errors():
     assert svc2.stats["retries"] == 0
 
 
+def test_service_does_not_retry_xla_errors():
+    # a refused compile or a device OOM fails the same way every time:
+    # it surfaces on the first attempt, not after retries and backoff
+    import jax
+    from repro.serve import PredictionService, WorkloadRequest
+    from repro.workloads.hpl import HPLFastModel
+    orig = HPLFastModel.sweep_models.__func__
+    calls = {"n": 0}
+
+    def oom(cls, models):
+        calls["n"] += 1
+        raise jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: out of HBM")
+
+    HPLFastModel.sweep_models = classmethod(oom)
+    try:
+        svc = PredictionService(backoff_s=1e-4)
+        with pytest.raises(jax.errors.JaxRuntimeError, match="EXHAUSTED"):
+            svc.predict_batch([WorkloadRequest(
+                rid=0, workload="hpl", platform="tpu-v5e-pod")])
+    finally:
+        HPLFastModel.sweep_models = classmethod(orig)
+    assert calls["n"] == 1 and svc.stats["retries"] == 0
+
+
 # ------------------------------------------------------------ ft layer
 
 def test_simulate_fault_impact_generic():

@@ -89,12 +89,19 @@ def test_waterfill_matches_ref_and_conserves():
     adj = (jax.random.uniform(jax.random.PRNGKey(4), (128, 128))
            < 0.05).astype(jnp.int8)
     caps = jax.random.uniform(jax.random.PRNGKey(5), (128,)) * 1e9 + 1e8
-    r_k = waterfill(adj, caps, use_kernel=True)
+    r_k = waterfill(adj, caps, use_kernel=True, interpret=True)
     r_r = waterfill_ref(adj, caps)
     np.testing.assert_allclose(np.asarray(r_k), np.asarray(r_r), rtol=1e-4)
     rates = np.minimum(np.asarray(r_r, np.float64), 1e30)
     usage = np.asarray(adj, np.float64).T @ rates
     assert (usage <= np.asarray(caps) * (1 + 1e-3)).all()
+
+
+def test_waterfill_kernel_rejects_unaligned_shapes():
+    # no silent switch to the jnp path: the kernel runs or the call fails
+    adj = jnp.ones((6, 100), jnp.int8)
+    with pytest.raises(ValueError, match="L % 128"):
+        waterfill(adj, jnp.ones((100,)), use_kernel=True, interpret=True)
 
 
 def test_waterfill_matches_des_network():

@@ -6,7 +6,6 @@ import dataclasses
 import jax
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.core.apps.hpl import HPLConfig
 from repro.core import fastsim
@@ -135,7 +134,7 @@ def test_prediction_service_batches_and_matches():
 
 def test_gradient_flows_through_recurrence():
     cfg = HPLConfig(N=2048, nb=128, P=4, Q=4)
-    with enable_x64(True):
+    with jax.enable_x64(True):
         g = jax.grad(lambda p: simulate_time_traced(cfg, p))(
             fastsim._f64_params(BASE))
     leaves = jax.tree_util.tree_leaves(g)

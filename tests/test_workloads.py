@@ -206,7 +206,6 @@ def test_step_sweep_matches_singles():
 
 def test_step_params_gradient_flows():
     jax = pytest.importorskip("jax")
-    from jax.experimental import enable_x64
     from repro.workloads import step_time_traced
 
     model = get_workload("transformer").fastsim_model(
@@ -217,7 +216,7 @@ def test_step_params_gradient_flows():
                                 link_bw=model.params.link_bw * scale)
         return step_time_traced(p)
 
-    with enable_x64(True):
+    with jax.enable_x64(True):
         g = jax.grad(loss)(1.0)
     assert g < 0                 # faster links -> shorter step
 
