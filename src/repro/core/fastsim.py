@@ -30,14 +30,13 @@ import contextlib
 import dataclasses
 import functools
 import math
-import time
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.obs.metrics import RATIO_BUCKETS, get_global_metrics
+from repro.obs.metrics import Timer, get_global_metrics
 
 from .apps.hpl import HPLConfig
 from .hardware.node import NodeModel
@@ -167,12 +166,14 @@ def _sim_core(N, nb, P, Q, prm: FastSimParams,
     def fact_time(k):
         """Panel-k factorization cost per row rank (SimBLAS closed forms):
         dger/dscal/idamax are Level-1/2 memory-bound.  Returns (P, B)."""
-        rem = N - k * nb
-        wf = width(rem).astype(f64)
-        mloc = numroc_vec(rem, k % P, P, P_max)
-        pf_bytes = 8.0 * (jnp.maximum(mloc * wf * wf - wf ** 3 / 3.0, 0.0)
-                          + 3.0 * mloc * wf)
-        return pf_bytes[:, None] / mem_bw + wf * (3 * theta) + wf * ar_lat
+        with jax.named_scope("hpl.fact"):
+            rem = N - k * nb
+            wf = width(rem).astype(f64)
+            mloc = numroc_vec(rem, k % P, P, P_max)
+            pf_bytes = 8.0 * (jnp.maximum(mloc * wf * wf - wf ** 3 / 3.0,
+                                          0.0) + 3.0 * mloc * wf)
+            return (pf_bytes[:, None] / mem_bw + wf * (3 * theta)
+                    + wf * ar_lat)
 
     # The T carry lives in *ring-order* space: stored column i holds the
     # absolute column (qk + i) % Q, so the broadcast root is always index
@@ -215,6 +216,9 @@ def _sim_core(N, nb, P, Q, prm: FastSimParams,
                       jnp.broadcast_to(T[:, :1, :], T.shape), T))
 
     def step(k, T, fact_done):
+        # each phase's ops carry a named scope (hpl.fact, hpl.bcast,
+        # hpl.swap, hpl.update, hpl.lookahead), so a profile sorts the
+        # ops of a step by phase
         rem = N - k * nb
         wf = width(rem).astype(f64)                      # panel width
         mloc = numroc_vec(rem, k % P, P, P_max)                    # (P,)
@@ -225,54 +229,62 @@ def _sim_core(N, nb, P, Q, prm: FastSimParams,
         # fact_done was computed in the previous iteration (lookahead):
         # the owning column factored panel k right after updating the
         # panel-k columns of step k-1, overlapping the rest of the update.
-        panel_bytes = 8.0 * (mloc + wf) * wf             # (P,)
-        hop = alpha + panel_bytes[:, None] / bcast_bw    # (P, B)
-        hi = hop[:, None, :] * iq.astype(f64)[None, :, None]
-        d = (T - hi).at[:, 0, :].set(fact_done)          # chain readiness
-        a = hi + cummax_cols(d)
-        arrival = a.at[:, 0, :].set(fact_done)           # root holds panel
+        with jax.named_scope("hpl.bcast"):
+            panel_bytes = 8.0 * (mloc + wf) * wf         # (P,)
+            hop = alpha + panel_bytes[:, None] / bcast_bw    # (P, B)
+            hi = hop[:, None, :] * iq.astype(f64)[None, :, None]
+            d = (T - hi).at[:, 0, :].set(fact_done)      # chain readiness
+            a = hi + cummax_cols(d)
+            arrival = a.at[:, 0, :].set(fact_done)       # root holds panel
 
-        # 3. row swaps: column ranks exchange the U strip (sync on colmax)
         # 4. update: dtrsm + dgemm on the local tile
-        u_bytes = 8.0 * wf * nloc                        # (Q,)
-        trsm = (wf * wf * nloc)[:, None] / peak + theta  # (Q, B)
-        gemm = (2.0 * mloc[:, None, None] * nloc[None, :, None] * wf
-                + 2.0 * mloc[:, None, None] * nloc[None, :, None]) \
-            / peak + theta                               # (P, Q, B)
+        with jax.named_scope("hpl.update"):
+            trsm = (wf * wf * nloc)[:, None] / peak + theta    # (Q, B)
+            gemm = (2.0 * mloc[:, None, None] * nloc[None, :, None] * wf
+                    + 2.0 * mloc[:, None, None] * nloc[None, :, None]) \
+                / peak + theta                           # (P, Q, B)
+        # 3. row swaps: column ranks exchange the U strip (sync on colmax)
         if P_max > 1:                    # P > 1 exactly (bucket(1) == 1)
-            swap = jnp.where(
-                u_bytes[:, None] > 0,
-                sw_rounds * (alpha + (u_bytes[:, None]
-                                      / jnp.maximum(sw_rounds, 1.0))
-                             / swap_bw)
-                + (4.0 * 8.0 * wf * nloc)[:, None] / mem_bw,
-                0.0)                                     # (Q, B)
-            # column sync: every rank of a column proceeds from the
-            # column max, so after_swap is row-independent — a (Q, B)
-            # row vector instead of a (P, Q, B) grid.
-            colmax = jnp.max(jnp.maximum(arrival, T), axis=0,
-                             where=row_on[:, None, None],
-                             initial=-jnp.inf)           # (Q, B)
-            after_swap = colmax + swap                   # (Q, B)
-            T_new = (after_swap + trsm)[None, :, :] + gemm
+            with jax.named_scope("hpl.swap"):
+                u_bytes = 8.0 * wf * nloc                # (Q,)
+                swap = jnp.where(
+                    u_bytes[:, None] > 0,
+                    sw_rounds * (alpha + (u_bytes[:, None]
+                                          / jnp.maximum(sw_rounds, 1.0))
+                                 / swap_bw)
+                    + (4.0 * 8.0 * wf * nloc)[:, None] / mem_bw,
+                    0.0)                                 # (Q, B)
+                # column sync: every rank of a column proceeds from the
+                # column max, so after_swap is row-independent — a (Q, B)
+                # row vector instead of a (P, Q, B) grid.
+                colmax = jnp.max(jnp.maximum(arrival, T), axis=0,
+                                 where=row_on[:, None, None],
+                                 initial=-jnp.inf)       # (Q, B)
+                after_swap = colmax + swap               # (Q, B)
+            with jax.named_scope("hpl.update"):
+                T_new = (after_swap + trsm)[None, :, :] + gemm
             as_next = after_swap[idx1]                   # (B,) static slice
         else:
-            after_swap = jnp.maximum(arrival, T)         # (1, Q, B)
-            T_new = after_swap + trsm[None, :, :] + gemm
+            with jax.named_scope("hpl.swap"):
+                after_swap = jnp.maximum(arrival, T)     # (1, Q, B)
+            with jax.named_scope("hpl.update"):
+                T_new = after_swap + trsm[None, :, :] + gemm
             as_next = after_swap[:, idx1, :]             # (P=1, B)
 
         # 1'. (lookahead) factor panel k+1 on its owning column, anchored
         # right after that column updates just the next panel's columns.
-        mloc_n = numroc_vec(jnp.maximum(rem - nb, 0), (k + 1) % P, P, P_max)
-        w_next = width(rem - nb).astype(f64)
-        gemm_nb = (2.0 * mloc_n[:, None] * w_next * wf) / peak \
-            + theta                                                 # (P, B)
         ft = fact_time(k + 1)
-        fact_next_overlap = as_next + gemm_nb + ft
-        fact_next_serial = T_new[:, idx1, :] + ft
-        fact_next = (lookahead * jnp.minimum(fact_next_overlap,
-                                             fact_next_serial)
-                     + (1.0 - lookahead) * fact_next_serial)
+        with jax.named_scope("hpl.lookahead"):
+            mloc_n = numroc_vec(jnp.maximum(rem - nb, 0), (k + 1) % P, P,
+                                P_max)
+            w_next = width(rem - nb).astype(f64)
+            gemm_nb = (2.0 * mloc_n[:, None] * w_next * wf) / peak \
+                + theta                                             # (P, B)
+            fact_next_overlap = as_next + gemm_nb + ft
+            fact_next_serial = T_new[:, idx1, :] + ft
+            fact_next = (lookahead * jnp.minimum(fact_next_overlap,
+                                                 fact_next_serial)
+                         + (1.0 - lookahead) * fact_next_serial)
         # the panel column cannot broadcast before finishing its own step
         # only when overlapping is off; with lookahead the bcast may start
         # mid-update (HPL posts it asynchronously).
@@ -397,40 +409,58 @@ def _compiled(n_panels_max: int, P_max: int, Q_max: int, mode: str):
         global _TRACE_COUNT
         _TRACE_COUNT += 1
         return core(N, nb, P, Q, prm, n_panels_max, P_max, Q_max)
+    # the program's name in HLO and profiles: jit_hpl_recurrence_<mode>
+    fn.__name__ = fn.__qualname__ = f"hpl_recurrence_{mode}"
     return jax.jit(jax.vmap(fn) if mode == "batch" else fn)
 
 
-def _record_dispatch(m, key: Tuple[int, int, int], pre_traces: int,
-                     dt: float, live: int, lanes: int) -> None:
-    """One compiled-program dispatch into the global metrics registry:
-    compile-cache hit/miss (and compile wall) per shape bucket, plus
-    sweep-lane occupancy — padding lanes are pure waste, so the ratio
-    is the sweep engine's utilization number."""
+def _call(fn, args, key: Tuple, live: int, lanes: int,
+          sharded: bool = False, prefix: str = "fastsim",
+          traces=trace_count) -> np.ndarray:
+    """Run one compiled program and bring its answer to the host.
+
+    With the global metrics registry on, the call is two spans on the
+    profiler's clock: ``<prefix>.launch`` (``fn(*args)`` until it
+    returns: argument conversion, host-to-device transfer, enqueue) and
+    ``<prefix>.wait`` (until the answer is on the host).  The dispatch is
+    recorded per shape bucket ``key`` as a compile-cache hit or miss
+    (``traces`` is the model's trace counter), with its lane occupancy
+    — padding lanes are pure waste — and, on hits only, the launch, wait
+    and whole-dispatch wall times (a miss's wall is the compile's)."""
+    m = get_global_metrics()
+    if not m.enabled:
+        return np.asarray(fn(*args))
+    pre = traces()
+    with Timer(span=f"{prefix}.launch") as launch:
+        out = fn(*args)
+    with Timer(span=f"{prefix}.wait") as wait:
+        out = np.asarray(out)
+    dt = launch.elapsed + wait.elapsed
     bucket = "x".join(str(b) for b in key)
-    misses = trace_count() - pre_traces
+    misses = traces() - pre
     if misses:
-        m.counter("fastsim.compile_misses", bucket=bucket).inc(misses)
-        m.histogram("fastsim.compile_wall_s", bucket=bucket).observe(dt)
+        m.counter(f"{prefix}.compile_misses", bucket=bucket).inc(misses)
+        m.histogram(f"{prefix}.compile_wall_s", bucket=bucket).observe(dt)
     else:
-        m.counter("fastsim.compile_hits", bucket=bucket).inc()
-        m.histogram("fastsim.dispatch_wall_s").observe(dt)
-    m.counter("fastsim.lanes_live").inc(live)
-    m.counter("fastsim.lanes_padded").inc(lanes - live)
-    m.histogram("fastsim.sweep_occupancy", RATIO_BUCKETS).observe(
-        live / lanes)
+        m.counter(f"{prefix}.compile_hits", bucket=bucket).inc()
+        m.histogram(f"{prefix}.launch_s").observe(launch.elapsed)
+        m.histogram(f"{prefix}.wait_s").observe(wait.elapsed)
+        m.histogram(f"{prefix}.dispatch_wall_s").observe(dt)
+    m.counter(f"{prefix}.lanes_live").inc(live)
+    m.counter(f"{prefix}.lanes_padded").inc(lanes - live)
+    _record_shard(m, sharded, prefix)
+    return out
+
+
+def _single_args(cfg: HPLConfig, prm: FastSimParams) -> tuple:
+    return (np.int64(cfg.N), np.int64(cfg.nb), np.int64(cfg.P),
+            np.int64(cfg.Q), _f64_params(prm))
 
 
 def _run_single(cfg: HPLConfig, prm: FastSimParams) -> float:
-    fn = _compiled(*bucket_key(cfg), "single")
-    m = get_global_metrics()
-    if not m.enabled:
-        return float(fn(np.int64(cfg.N), np.int64(cfg.nb),
-                        np.int64(cfg.P), np.int64(cfg.Q), _f64_params(prm)))
-    pre, t0 = trace_count(), time.perf_counter()
-    out = float(fn(np.int64(cfg.N), np.int64(cfg.nb),
-                   np.int64(cfg.P), np.int64(cfg.Q), _f64_params(prm)))
-    _record_dispatch(m, bucket_key(cfg), pre, time.perf_counter() - t0, 1, 1)
-    return out
+    key = bucket_key(cfg)
+    return float(_call(_compiled(*key, "single"), _single_args(cfg, prm),
+                       key, 1, 1))
 
 
 def _stack_params(prm_list: Sequence[FastSimParams],
@@ -508,63 +538,74 @@ def sweep_hpl(configs: Configs, params: Params, *,
         raise ValueError(
             f"sweep_hpl: {len(cfg_list)} configs vs {len(prm_list)} params "
             "(must match, or one side must be a single scenario)")
-    if bucket is not None:
-        return _sweep_forced_bucket(cfg_list, prm_list, bucket)
+    m = get_global_metrics()
+    pre = trace_count()
+    times = np.empty(len(cfg_list), np.float64)
+    with jax.enable_x64(True):
+        with (Timer(span="fastsim.prepare") if m.enabled
+              else contextlib.nullcontext()) as prep:
+            plan = (_plan(cfg_list, prm_list) if bucket is None
+                    else _plan_forced(cfg_list, prm_list, bucket))
+        for fn, args, idxs, key, lanes, sharded in plan:
+            out = _call(fn, args, key, len(idxs), lanes, sharded)
+            times[idxs] = np.reshape(out, -1)[:len(idxs)]
+    if m.enabled and trace_count() == pre:
+        m.histogram("fastsim.prepare_s").observe(prep.elapsed)
+    return [_result(cfg, float(t)) for cfg, t in zip(cfg_list, times)]
 
+
+def _plan(cfg_list: Sequence[HPLConfig],
+          prm_list: Sequence[FastSimParams]) -> List[tuple]:
+    """The host's sweep assembly: the compiled calls ``(fn, args,
+    scenario indices, shape bucket, lanes, sharded)`` that answer every
+    scenario — one params-mode call per shared geometry, one batch-mode
+    call per shape bucket of the rest, single calls for loners."""
     by_cfg: Dict[Tuple[int, int, int, int], List[int]] = {}
     for idx, cfg in enumerate(cfg_list):
         by_cfg.setdefault((cfg.N, cfg.nb, cfg.P, cfg.Q), []).append(idx)
 
-    times = np.empty(len(cfg_list), np.float64)
+    plan: List[tuple] = []
     mixed: Dict[Tuple[int, int, int], List[int]] = {}
-    m = get_global_metrics()
-    with jax.enable_x64(True):
-        for (N, nb, P, Q), idxs in by_cfg.items():
-            key = bucket_key(cfg_list[idxs[0]])
-            if len(idxs) == 1:
-                mixed.setdefault(key, []).append(idxs[0])
-                continue
-            lanes = _pad_lanes(idxs)
-            fn = _compiled(*key, "params")
-            (stacked,), sharded = _shard_lanes(
-                len(lanes), _stack_params(prm_list, lanes))
-            if m.enabled:
-                pre, t0 = trace_count(), time.perf_counter()
-            out = np.asarray(fn(np.int64(N), np.int64(nb), np.int64(P),
-                                np.int64(Q), stacked))
-            if m.enabled:
-                _record_dispatch(m, key, pre, time.perf_counter() - t0,
-                                 len(idxs), len(lanes))
-                _record_shard(m, sharded)
-            times[idxs] = out[:len(idxs)]
-        for key, idxs in mixed.items():
-            if len(idxs) == 1:
-                times[idxs[0]] = _run_single(cfg_list[idxs[0]],
-                                             prm_list[idxs[0]])
-                continue
-            lanes = _pad_lanes(idxs)
-            geom = np.asarray([[cfg_list[i].N, cfg_list[i].nb,
-                                cfg_list[i].P, cfg_list[i].Q]
-                               for i in lanes], np.int64)
-            fn = _compiled(*key, "batch")
-            args, sharded = _shard_lanes(
-                len(lanes), geom[:, 0], geom[:, 1], geom[:, 2], geom[:, 3],
-                _stack_params(prm_list, lanes))
-            if m.enabled:
-                pre, t0 = trace_count(), time.perf_counter()
-            out = np.asarray(fn(*args))
-            if m.enabled:
-                _record_dispatch(m, key, pre, time.perf_counter() - t0,
-                                 len(idxs), len(lanes))
-                _record_shard(m, sharded)
-            times[idxs] = out[:len(idxs)]
-    return [_result(cfg, float(t)) for cfg, t in zip(cfg_list, times)]
+    for (N, nb, P, Q), idxs in by_cfg.items():
+        key = bucket_key(cfg_list[idxs[0]])
+        if len(idxs) == 1:
+            mixed.setdefault(key, []).append(idxs[0])
+            continue
+        lanes = _pad_lanes(idxs)
+        (stacked,), sharded = _shard_lanes(
+            len(lanes), _stack_params(prm_list, lanes))
+        plan.append((_compiled(*key, "params"),
+                     (np.int64(N), np.int64(nb), np.int64(P), np.int64(Q),
+                      stacked), idxs, key, len(lanes), sharded))
+    for key, idxs in mixed.items():
+        if len(idxs) == 1:
+            plan.append((_compiled(*key, "single"),
+                         _single_args(cfg_list[idxs[0]], prm_list[idxs[0]]),
+                         idxs, key, 1, False))
+        else:
+            plan.append(_batch_call(cfg_list, prm_list, idxs, key))
+    return plan
 
 
-def _sweep_forced_bucket(cfg_list: Sequence[HPLConfig],
-                         prm_list: Sequence[FastSimParams],
-                         bucket: Tuple[int, int, int]) -> List[dict]:
-    """One 'batch'-mode dispatch for the whole sweep under a shared
+def _batch_call(cfg_list: Sequence[HPLConfig],
+                prm_list: Sequence[FastSimParams], idxs: List[int],
+                key: Tuple[int, int, int]) -> tuple:
+    """One batch-mode call over scenarios ``idxs`` in shape bucket
+    ``key``: geometry and params both ride the padded lane axis."""
+    lanes = _pad_lanes(idxs)
+    geom = np.asarray([[cfg_list[i].N, cfg_list[i].nb,
+                        cfg_list[i].P, cfg_list[i].Q]
+                       for i in lanes], np.int64)
+    args, sharded = _shard_lanes(
+        len(lanes), geom[:, 0], geom[:, 1], geom[:, 2], geom[:, 3],
+        _stack_params(prm_list, lanes))
+    return (_compiled(*key, "batch"), args, idxs, key, len(lanes), sharded)
+
+
+def _plan_forced(cfg_list: Sequence[HPLConfig],
+                 prm_list: Sequence[FastSimParams],
+                 bucket: Tuple[int, int, int]) -> List[tuple]:
+    """One batch-mode call for the whole sweep under a shared
     (rounded-up) bucket — exactly one traced program per distinct
     forced bucket, however many geometries are mixed in."""
     n_panels_max, P_max, Q_max = (_bucket(b) for b in bucket)
@@ -575,23 +616,5 @@ def _sweep_forced_bucket(cfg_list: Sequence[HPLConfig],
                 f"sweep_hpl: config (N={cfg.N}, nb={cfg.nb}, P={cfg.P}, "
                 f"Q={cfg.Q}) exceeds forced bucket "
                 f"({n_panels_max}, {P_max}, {Q_max})")
-    lanes = _pad_lanes(list(range(len(cfg_list))))
-    geom = np.asarray([[cfg_list[i].N, cfg_list[i].nb,
-                        cfg_list[i].P, cfg_list[i].Q]
-                       for i in lanes], np.int64)
-    m = get_global_metrics()
-    with jax.enable_x64(True):
-        fn = _compiled(n_panels_max, P_max, Q_max, "batch")
-        args, sharded = _shard_lanes(
-            len(lanes), geom[:, 0], geom[:, 1], geom[:, 2], geom[:, 3],
-            _stack_params(prm_list, lanes))
-        if m.enabled:
-            pre, t0 = trace_count(), time.perf_counter()
-        out = np.asarray(fn(*args))
-        if m.enabled:
-            _record_dispatch(m, (n_panels_max, P_max, Q_max), pre,
-                             time.perf_counter() - t0, len(cfg_list),
-                             len(lanes))
-            _record_shard(m, sharded)
-    return [_result(cfg, float(t))
-            for cfg, t in zip(cfg_list, out[:len(cfg_list)])]
+    return [_batch_call(cfg_list, prm_list, list(range(len(cfg_list))),
+                        (n_panels_max, P_max, Q_max))]

@@ -28,7 +28,14 @@ associative and commutative — property-tested):
 Instruments are keyed by ``(name, labels)``; snapshots flatten the key
 to ``name{k="v",...}`` with sorted labels so equal registries serialize
 to equal JSON (deterministic snapshots).  ``Timer`` is the span-style
-context manager over a histogram.
+context manager over a histogram; an enabled registry's timer also holds
+a ``jax.profiler.TraceAnnotation``, so its interval lands on the host
+plane of a profiler trace, on the same clock as the device's ops.
+
+An enabled registry is safe to share between threads: one lock per
+registry guards instrument creation, every update and snapshots (a
+``+=`` can lose an update when a thread switch lands between its load
+and its store).  The null registry takes no lock.
 
 A process-global registry hook (``set_global_metrics``) lets the
 module-shaped layers — ``core.fastsim``, ``workloads.stepsim`` — report
@@ -42,6 +49,7 @@ import bisect
 import contextlib
 import json
 import re
+import threading
 import time
 from typing import Any, Dict, Iterable, List, Mapping, Optional, Tuple
 
@@ -98,33 +106,39 @@ def parse_key(key: str) -> Tuple[str, Labels]:
 
 
 # ---------------------------------------------------------- instruments
+# Each instrument updates under a lock: its registry's, or one of its own
+# when built alone.
 class Counter:
-    __slots__ = ("value",)
+    __slots__ = ("value", "_lock")
 
-    def __init__(self, value: float = 0.0):
+    def __init__(self, value: float = 0.0, lock=None):
         self.value = value
+        self._lock = threading.Lock() if lock is None else lock
 
     def inc(self, n: float = 1.0) -> None:
         if n < 0:
             raise ValueError(f"counter increment must be >= 0, got {n}")
-        self.value += n
+        with self._lock:
+            self.value += n
 
 
 class Gauge:
-    __slots__ = ("value", "max", "min")
+    __slots__ = ("value", "max", "min", "_lock")
 
-    def __init__(self):
+    def __init__(self, lock=None):
         self.value: float = 0.0
         self.max: Optional[float] = None
         self.min: Optional[float] = None
+        self._lock = threading.Lock() if lock is None else lock
 
     def set(self, v: float) -> None:
         v = float(v)
-        self.value = v
-        if self.max is None or v > self.max:
-            self.max = v
-        if self.min is None or v < self.min:
-            self.min = v
+        with self._lock:
+            self.value = v
+            if self.max is None or v > self.max:
+                self.max = v
+            if self.min is None or v < self.min:
+                self.min = v
 
 
 class Histogram:
@@ -132,9 +146,10 @@ class Histogram:
     ``counts`` has ``len(bounds) + 1`` entries, the last being the
     overflow (+Inf) bucket."""
 
-    __slots__ = ("bounds", "counts", "sum", "count", "min", "max")
+    __slots__ = ("bounds", "counts", "sum", "count", "min", "max", "_lock")
 
-    def __init__(self, bounds: Iterable[float] = DEFAULT_LATENCY_BUCKETS):
+    def __init__(self, bounds: Iterable[float] = DEFAULT_LATENCY_BUCKETS,
+                 lock=None):
         self.bounds: Tuple[float, ...] = tuple(float(b) for b in bounds)
         if not self.bounds or list(self.bounds) != sorted(set(self.bounds)):
             raise ValueError(
@@ -145,16 +160,19 @@ class Histogram:
         self.count = 0
         self.min: Optional[float] = None
         self.max: Optional[float] = None
+        self._lock = threading.Lock() if lock is None else lock
 
     def observe(self, v: float) -> None:
         v = float(v)
-        self.counts[bisect.bisect_left(self.bounds, v)] += 1
-        self.sum += v
-        self.count += 1
-        if self.max is None or v > self.max:
-            self.max = v
-        if self.min is None or v < self.min:
-            self.min = v
+        i = bisect.bisect_left(self.bounds, v)
+        with self._lock:
+            self.counts[i] += 1
+            self.sum += v
+            self.count += 1
+            if self.max is None or v > self.max:
+                self.max = v
+            if self.min is None or v < self.min:
+                self.min = v
 
     def quantile(self, q: float) -> float:
         """Bucket-interpolated quantile in [0, 1] (0.0 when empty)."""
@@ -179,23 +197,38 @@ class Histogram:
 
 
 class Timer:
-    """Span-style context manager: observes elapsed wall seconds into a
-    histogram on exit; ``.elapsed`` holds the last measurement."""
+    """Span-style context manager: observes elapsed wall seconds into
+    ``hist`` on exit (nothing when it is None; ``.elapsed`` holds the
+    last measurement) and, given a ``span`` name, holds a
+    ``jax.profiler.TraceAnnotation`` of that name around the interval.
+    It never waits on the device: a span around an asynchronous call
+    times the call, not the work it enqueued."""
 
-    __slots__ = ("_hist", "_t0", "elapsed")
+    __slots__ = ("_hist", "_span", "_ann", "_t0", "elapsed")
 
-    def __init__(self, hist: Histogram):
+    def __init__(self, hist: Optional[Histogram] = None,
+                 span: Optional[str] = None):
         self._hist = hist
+        self._span = span
+        self._ann = None
         self._t0 = 0.0
         self.elapsed: Optional[float] = None
 
     def __enter__(self) -> "Timer":
+        if self._span is not None:
+            from jax.profiler import TraceAnnotation   # jax on first use
+            self._ann = TraceAnnotation(self._span)
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
         self.elapsed = time.perf_counter() - self._t0
-        self._hist.observe(self.elapsed)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
+        if self._hist is not None:
+            self._hist.observe(self.elapsed)
 
 
 # ---------------------------------------------------------- null object
@@ -266,7 +299,8 @@ class _NullMetrics:
     def histogram(self, name: str, buckets=None, **labels) -> _NullHistogram:
         return _NULL_HISTOGRAM
 
-    def timer(self, name: str, buckets=None, **labels) -> _NullTimer:
+    def timer(self, name: str, buckets=None, *, span=None,
+              **labels) -> _NullTimer:
         return _NULL_TIMER
 
     def snapshot(self) -> Dict[str, Dict[str, Any]]:
@@ -286,11 +320,13 @@ NULL_METRICS = _NullMetrics()
 class MetricsRegistry:
     """The enabled registry: instruments are created on first use and
     keyed ``(name, sorted labels)``; repeat lookups return the same
-    object, so call sites may cache them."""
+    object, so call sites may cache them.  Its instruments share the
+    registry's lock."""
 
     enabled = True
 
     def __init__(self):
+        self._lock = threading.Lock()
         self._counters: Dict[Tuple[str, Labels], Counter] = {}
         self._gauges: Dict[Tuple[str, Labels], Gauge] = {}
         self._histograms: Dict[Tuple[str, Labels], Histogram] = {}
@@ -300,14 +336,16 @@ class MetricsRegistry:
         key = (name, _labels_of(labels))
         c = self._counters.get(key)
         if c is None:
-            c = self._counters[key] = Counter()
+            with self._lock:
+                c = self._counters.setdefault(key, Counter(lock=self._lock))
         return c
 
     def gauge(self, name: str, **labels) -> Gauge:
         key = (name, _labels_of(labels))
         g = self._gauges.get(key)
         if g is None:
-            g = self._gauges[key] = Gauge()
+            with self._lock:
+                g = self._gauges.setdefault(key, Gauge(lock=self._lock))
         return g
 
     def histogram(self, name: str, buckets: Optional[Iterable[float]] = None,
@@ -315,27 +353,35 @@ class MetricsRegistry:
         key = (name, _labels_of(labels))
         h = self._histograms.get(key)
         if h is None:
-            h = self._histograms[key] = Histogram(
-                DEFAULT_LATENCY_BUCKETS if buckets is None else buckets)
+            with self._lock:
+                h = self._histograms.setdefault(key, Histogram(
+                    DEFAULT_LATENCY_BUCKETS if buckets is None else buckets,
+                    lock=self._lock))
         return h
 
     def timer(self, name: str, buckets: Optional[Iterable[float]] = None,
-              **labels) -> Timer:
-        return Timer(self.histogram(name, buckets, **labels))
+              *, span: Optional[str] = None, **labels) -> Timer:
+        """A span observed into histogram ``name`` and annotated on the
+        profiler trace as ``span`` (default: ``name`` less a trailing
+        ``_s``, so ``fastsim.launch_s`` is the span ``fastsim.launch``)."""
+        if span is None:
+            span = name[:-2] if name.endswith("_s") else name
+        return Timer(self.histogram(name, buckets, **labels), span)
 
     # ------------------------------------------------------- snapshots
     def snapshot(self) -> Dict[str, Dict[str, Any]]:
         """Deterministic (key-sorted) JSON-safe snapshot of every
         instrument; equal histories give equal snapshots."""
-        counters = {flatten_key(*k): c.value
-                    for k, c in self._counters.items()}
-        gauges = {flatten_key(*k): {"value": g.value, "max": g.max,
-                                    "min": g.min}
-                  for k, g in self._gauges.items()}
-        hists = {flatten_key(*k): {
-            "bounds": list(h.bounds), "counts": list(h.counts),
-            "sum": h.sum, "count": h.count, "min": h.min, "max": h.max}
-            for k, h in self._histograms.items()}
+        with self._lock:
+            counters = {flatten_key(*k): c.value
+                        for k, c in self._counters.items()}
+            gauges = {flatten_key(*k): {"value": g.value, "max": g.max,
+                                        "min": g.min}
+                      for k, g in self._gauges.items()}
+            hists = {flatten_key(*k): {
+                "bounds": list(h.bounds), "counts": list(h.counts),
+                "sum": h.sum, "count": h.count, "min": h.min, "max": h.max}
+                for k, h in self._histograms.items()}
         return {"counters": dict(sorted(counters.items())),
                 "gauges": dict(sorted(gauges.items())),
                 "histograms": dict(sorted(hists.items()))}
@@ -358,38 +404,42 @@ class MetricsRegistry:
         counters add, gauges max, histogram buckets add elementwise
         (same-name histograms must share bounds).  Returns self."""
         snap = other.snapshot() if hasattr(other, "snapshot") else other
-        for key, v in snap.get("counters", {}).items():
-            name, labels = parse_key(key)
-            self._counters.setdefault((name, labels), Counter()).value += v
-        for key, gv in snap.get("gauges", {}).items():
-            name, labels = parse_key(key)
-            g = self._gauges.setdefault((name, labels), Gauge())
-            g.value = max(g.value, gv["value"]) if g.max is not None \
-                else gv["value"]
-            for attr, pick in (("max", max), ("min", min)):
-                mine, theirs = getattr(g, attr), gv.get(attr)
-                if theirs is not None:
-                    setattr(g, attr,
-                            theirs if mine is None else pick(mine, theirs))
-        for key, hv in snap.get("histograms", {}).items():
-            name, labels = parse_key(key)
-            hkey = (name, labels)
-            h = self._histograms.get(hkey)
-            if h is None:
-                h = self._histograms[hkey] = Histogram(hv["bounds"])
-            if list(h.bounds) != list(hv["bounds"]):
-                raise ValueError(
-                    f"cannot merge histogram {key!r}: bounds differ "
-                    f"({list(h.bounds)} vs {list(hv['bounds'])})")
-            for i, c in enumerate(hv["counts"]):
-                h.counts[i] += c
-            h.sum += hv["sum"]
-            h.count += hv["count"]
-            for attr, pick in (("max", max), ("min", min)):
-                mine, theirs = getattr(h, attr), hv.get(attr)
-                if theirs is not None:
-                    setattr(h, attr,
-                            theirs if mine is None else pick(mine, theirs))
+        with self._lock:
+            for key, v in snap.get("counters", {}).items():
+                name, labels = parse_key(key)
+                self._counters.setdefault(
+                    (name, labels), Counter(lock=self._lock)).value += v
+            for key, gv in snap.get("gauges", {}).items():
+                name, labels = parse_key(key)
+                g = self._gauges.setdefault((name, labels),
+                                            Gauge(lock=self._lock))
+                g.value = max(g.value, gv["value"]) if g.max is not None \
+                    else gv["value"]
+                for attr, pick in (("max", max), ("min", min)):
+                    mine, theirs = getattr(g, attr), gv.get(attr)
+                    if theirs is not None:
+                        setattr(g, attr, theirs if mine is None
+                                else pick(mine, theirs))
+            for key, hv in snap.get("histograms", {}).items():
+                name, labels = parse_key(key)
+                hkey = (name, labels)
+                h = self._histograms.get(hkey)
+                if h is None:
+                    h = self._histograms[hkey] = Histogram(
+                        hv["bounds"], lock=self._lock)
+                if list(h.bounds) != list(hv["bounds"]):
+                    raise ValueError(
+                        f"cannot merge histogram {key!r}: bounds differ "
+                        f"({list(h.bounds)} vs {list(hv['bounds'])})")
+                for i, c in enumerate(hv["counts"]):
+                    h.counts[i] += c
+                h.sum += hv["sum"]
+                h.count += hv["count"]
+                for attr, pick in (("max", max), ("min", min)):
+                    mine, theirs = getattr(h, attr), hv.get(attr)
+                    if theirs is not None:
+                        setattr(h, attr, theirs if mine is None
+                                else pick(mine, theirs))
         return self
 
     # --------------------------------------------------------- export

@@ -78,8 +78,13 @@ merge).  Counters back every hardening path (retries, deadline
 fallbacks, degraded answers, isolated errors, rank-guard trips,
 dispatch failures), per-request latency and wave size are recorded as
 histograms (distributions, not point numbers), and the queue depth is a
-gauge with a tracked peak.  ``svc.metrics.to_prometheus()`` is the
-scrape surface; ``svc.manifest()`` emits one NDJSON run-manifest line.
+gauge with a tracked peak.  Each wave is four spans, timed into
+``serve.{resolve,flush,dispatch,assemble}_s`` histograms and annotated
+on the profiler's clock as ``serve.resolve`` (binding the requests),
+``serve.flush`` (one wave), ``serve.dispatch`` (one family's sweep,
+retries included) and ``serve.assemble`` (attaching the results).
+``svc.metrics.to_prometheus()`` is the scrape surface;
+``svc.manifest()`` emits one NDJSON run-manifest line.
 Breakdown DES runs report engine telemetry into the same registry.
 
 Dispatch is all-or-nothing per wave: every family's sweep runs before
@@ -328,20 +333,21 @@ class PredictionService:
         local devices."""
         models = [r._bound[2] for r in reqs]
         delay = self.backoff_s
-        for attempt in range(self.retries + 1):
-            try:
-                if self.shard:
-                    with lane_sharding(True):
-                        return model_cls.sweep_models(models)
-                return model_cls.sweep_models(models)
-            except self.TRANSIENT as exc:
-                if (attempt == self.retries
-                        or isinstance(exc, jax.errors.JaxRuntimeError)):
-                    raise
-                self.stats["retries"] += 1
-                self.metrics.counter("serve.retries").inc()
-                time.sleep(delay)
-                delay *= 2.0
+        with self.metrics.timer("serve.dispatch_s"):
+            for attempt in range(self.retries + 1):
+                try:
+                    if self.shard:
+                        with lane_sharding(True):
+                            return model_cls.sweep_models(models)
+                    return model_cls.sweep_models(models)
+                except self.TRANSIENT as exc:
+                    if (attempt == self.retries
+                            or isinstance(exc, jax.errors.JaxRuntimeError)):
+                        raise
+                    self.stats["retries"] += 1
+                    self.metrics.counter("serve.retries").inc()
+                    time.sleep(delay)
+                    delay *= 2.0
 
     def _attach_breakdown(self, req: WorkloadRequest, out: dict) -> None:
         """Run the traced DES under the request's remaining wall budget;
@@ -428,65 +434,72 @@ class PredictionService:
         behind the wave — the service stays reusable with a clean queue
         (cache hits served before the failure keep their good results)."""
         results: Dict[int, dict] = {}
+        while self._queue:
+            with self.metrics.timer("serve.flush_s"):
+                self._flush_wave(results)
+        return results
+
+    def _flush_wave(self, results: Dict[int, dict]) -> None:
+        """Serve the queue's next wave of up to ``max_batch`` requests
+        into ``results`` (see ``flush``)."""
         m = self.metrics
         cache = self.cache
-        while self._queue:
-            wave = self._queue[:self.max_batch]
-            del self._queue[:self.max_batch]
-            if m.enabled:
-                m.histogram("serve.wave_size", COUNT_BUCKETS).observe(
-                    len(wave))
-                m.gauge("serve.queue_depth").set(len(self._queue))
-            to_dispatch: List[WorkloadRequest] = []
-            followers: Dict[str, List[WorkloadRequest]] = {}
-            served_ids: set = set()
-            if cache is None:
-                to_dispatch = list(wave)
-            else:
-                leaders: Dict[str, WorkloadRequest] = {}
-                for req in wave:
-                    req._ckey = key = self._cache_key(req)
-                    if key is None:               # uncacheable: dispatch
-                        to_dispatch.append(req)
-                        continue
-                    hit = cache.get(key)
-                    if hit is not None:
-                        hit["cached"] = True      # provenance stamp; the
-                        #   payload under it is bit-identical to a miss
-                        self._finish(req, hit, results)
-                        served_ids.add(id(req))
-                        self.stats["cache_hits"] += 1
-                        m.counter("serve.cache_hits").inc()
-                        continue
-                    self.stats["cache_misses"] += 1
-                    m.counter("serve.cache_misses").inc()
-                    if key in leaders:            # coalesce onto leader
-                        followers.setdefault(key, []).append(req)
-                    else:
-                        leaders[key] = req
-                        to_dispatch.append(req)
-            by_family: Dict[type, List[WorkloadRequest]] = {}
-            for req in to_dispatch:
-                by_family.setdefault(type(req._bound[2]), []).append(req)
-            dispatched: List[tuple] = []
-            try:
-                for model_cls, reqs in by_family.items():
-                    dispatched.append((reqs, self._dispatch(model_cls, reqs)))
-                    self.stats["sweeps"] += 1
-                    m.counter("serve.sweeps").inc()
-            except Exception as exc:
-                # the wave is already off the queue; stamp every request
-                # not already served from cache so callers holding the
-                # objects see the failure, then surface it.  Nothing from
-                # a failed wave is ever inserted into the cache.
-                err = {"status": "error", "error": str(exc),
-                       "error_type": type(exc).__name__}
-                for req in wave:
-                    if id(req) not in served_ids:
-                        req.result = dict(err)
-                self.stats["errors"] += 1
-                m.counter("serve.dispatch_failures").inc()
-                raise
+        wave = self._queue[:self.max_batch]
+        del self._queue[:self.max_batch]
+        if m.enabled:
+            m.histogram("serve.wave_size", COUNT_BUCKETS).observe(len(wave))
+            m.gauge("serve.queue_depth").set(len(self._queue))
+        to_dispatch: List[WorkloadRequest] = []
+        followers: Dict[str, List[WorkloadRequest]] = {}
+        served_ids: set = set()
+        if cache is None:
+            to_dispatch = list(wave)
+        else:
+            leaders: Dict[str, WorkloadRequest] = {}
+            for req in wave:
+                req._ckey = key = self._cache_key(req)
+                if key is None:               # uncacheable: dispatch
+                    to_dispatch.append(req)
+                    continue
+                hit = cache.get(key)
+                if hit is not None:
+                    hit["cached"] = True      # provenance stamp; the
+                    #   payload under it is bit-identical to a miss
+                    self._finish(req, hit, results)
+                    served_ids.add(id(req))
+                    self.stats["cache_hits"] += 1
+                    m.counter("serve.cache_hits").inc()
+                    continue
+                self.stats["cache_misses"] += 1
+                m.counter("serve.cache_misses").inc()
+                if key in leaders:            # coalesce onto leader
+                    followers.setdefault(key, []).append(req)
+                else:
+                    leaders[key] = req
+                    to_dispatch.append(req)
+        by_family: Dict[type, List[WorkloadRequest]] = {}
+        for req in to_dispatch:
+            by_family.setdefault(type(req._bound[2]), []).append(req)
+        dispatched: List[tuple] = []
+        try:
+            for model_cls, reqs in by_family.items():
+                dispatched.append((reqs, self._dispatch(model_cls, reqs)))
+                self.stats["sweeps"] += 1
+                m.counter("serve.sweeps").inc()
+        except Exception as exc:
+            # the wave is already off the queue; stamp every request
+            # not already served from cache so callers holding the
+            # objects see the failure, then surface it.  Nothing from
+            # a failed wave is ever inserted into the cache.
+            err = {"status": "error", "error": str(exc),
+                   "error_type": type(exc).__name__}
+            for req in wave:
+                if id(req) not in served_ids:
+                    req.result = dict(err)
+            self.stats["errors"] += 1
+            m.counter("serve.dispatch_failures").inc()
+            raise
+        with m.timer("serve.assemble_s"):
             for reqs, res in dispatched:
                 for req, out in zip(reqs, res):
                     out = dict(out)
@@ -507,16 +520,15 @@ class PredictionService:
                         self._finish(dup, copy_payload(out), results)
                         self.stats["coalesced"] += 1
                         m.counter("serve.coalesced").inc()
-            self.stats["batches"] += 1
-            self.stats["scenarios"] += len(wave)
-            if m.enabled:
-                m.counter("serve.batches").inc()
-                m.counter("serve.scenarios").inc(len(wave))
-                if cache is not None:
-                    m.gauge("serve.cache_entries").set(len(cache))
-                    m.gauge("serve.cache_occupancy").set(
-                        len(cache) / cache.max_entries)
-        return results
+        self.stats["batches"] += 1
+        self.stats["scenarios"] += len(wave)
+        if m.enabled:
+            m.counter("serve.batches").inc()
+            m.counter("serve.scenarios").inc(len(wave))
+            if cache is not None:
+                m.gauge("serve.cache_entries").set(len(cache))
+                m.gauge("serve.cache_occupancy").set(
+                    len(cache) / cache.max_entries)
 
     def predict_batch(self, requests: Sequence[WorkloadRequest], *,
                       isolate_errors: bool = False) -> Dict[int, dict]:
@@ -531,8 +543,9 @@ class PredictionService:
         an empty (or all-failed) wave leaves the queue clean."""
         requests = list(requests)
         if not isolate_errors:
-            for req in requests:
-                self._resolve(req)
+            with self.metrics.timer("serve.resolve_s"):
+                for req in requests:
+                    self._resolve(req)
             if not requests:
                 return {}
             for req in requests:
@@ -540,17 +553,18 @@ class PredictionService:
             return self.flush()
         results: Dict[int, dict] = {}
         good: List[WorkloadRequest] = []
-        for req in requests:
-            try:
-                self._resolve(req)
-                good.append(req)
-            except Exception as exc:
-                err = {"status": "error", "error": str(exc),
-                       "error_type": type(exc).__name__}
-                req.result = err
-                results[req.rid] = err
-                self.stats["errors"] += 1
-                self.metrics.counter("serve.errors_isolated").inc()
+        with self.metrics.timer("serve.resolve_s"):
+            for req in requests:
+                try:
+                    self._resolve(req)
+                    good.append(req)
+                except Exception as exc:
+                    err = {"status": "error", "error": str(exc),
+                           "error_type": type(exc).__name__}
+                    req.result = err
+                    results[req.rid] = err
+                    self.stats["errors"] += 1
+                    self.metrics.counter("serve.errors_isolated").inc()
         for req in good:
             self.submit(req)
         if good:

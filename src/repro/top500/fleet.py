@@ -19,6 +19,7 @@ Machines at or below ``max_ranks`` simulate at full size (proxy scale
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import math
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -108,66 +109,71 @@ def predict_fleet(source, *,
 
     ``metrics`` (a ``repro.obs.MetricsRegistry``) opts the run into
     fleet telemetry: machine/compile counters, per-provenance-source
-    counts, per-phase wall times (tune / sweep / calibrate) and the
-    fitted family calibration factors as gauges.  The registry rides on
-    the returned report so ``report.run_manifest()`` can emit the
-    per-run NDJSON artifact the campaign layer consumes.
+    counts, the fitted family calibration factors as gauges, and one
+    span per phase — ``infer`` (rows only), ``tune``, ``params``,
+    ``bucket``, ``sweep``, ``report``, ``calibrate`` — timed into
+    ``fleet.phase_wall_s{phase=...}`` and annotated on the profiler's
+    clock as ``fleet.<phase>``; with the global registry on, the sweep's
+    own ``fastsim.*`` spans nest inside ``fleet.sweep``.  The registry
+    rides on the returned report so ``report.run_manifest()`` can emit
+    the per-run NDJSON artifact the campaign layer consumes.
     """
-    import time as _time
-
     from repro.core.fastsim import sweep_hpl, trace_count
     from repro.obs.metrics import NULL_METRICS
 
     m = metrics if metrics is not None else NULL_METRICS
+
+    def phase(name: str):
+        return m.timer("fleet.phase_wall_s", phase=name,
+                       span=f"fleet.{name}")
+
     tuning = tuning or FleetTuning()
     items = list(source)
     if not items:
         raise ValueError("predict_fleet: no machines to predict (did "
                          "the parser skip every row?)")
     if isinstance(items[0], Top500Row):
-        platforms = infer_platforms(items, **(infer_kw or {}))
+        with phase("infer"):
+            platforms = infer_platforms(items, **(infer_kw or {}))
     else:
         platforms = items
 
-    t0 = _time.perf_counter()
-    entries: List[FleetEntry] = []
-    for plat in platforms:
-        cfg, scale = tune_scenario(plat, tuning)
-        entries.append(FleetEntry(
-            platform=plat, cfg=cfg, scale=scale,
-            family=fabric_group(plat),
-            published_tflops=plat.scale.reported_tflops))
-    if m.enabled:
-        m.histogram("fleet.phase_wall_s", phase="tune").observe(
-            _time.perf_counter() - t0)
-        m.counter("fleet.machines").inc(len(entries))
-        for e in entries:
-            for src, _ in e.platform.provenance:
-                m.counter("fleet.provenance", source=src).inc()
-
-    bucket = fleet_bucket([e.cfg for e in entries])
+    with phase("tune"):
+        entries: List[FleetEntry] = []
+        for plat in platforms:
+            cfg, scale = tune_scenario(plat, tuning)
+            entries.append(FleetEntry(
+                platform=plat, cfg=cfg, scale=scale,
+                family=fabric_group(plat),
+                published_tflops=plat.scale.reported_tflops))
+    with phase("params"):
+        params = [e.platform.fastsim() for e in entries]
+    with phase("bucket"):
+        cfgs = [e.cfg for e in entries]
+        bucket = fleet_bucket(cfgs)
     compiles0 = trace_count()
-    t0 = _time.perf_counter()
-    results = sweep_hpl([e.cfg for e in entries],
-                        [e.platform.fastsim() for e in entries],
-                        bucket=bucket)
+    with phase("sweep"):
+        results = sweep_hpl(cfgs, params, bucket=bucket)
     compiles = trace_count() - compiles0
-    if m.enabled:
-        m.histogram("fleet.phase_wall_s", phase="sweep").observe(
-            _time.perf_counter() - t0)
-        m.counter("fleet.compiles").inc(compiles)
-    for e, res in zip(entries, results):
-        e.predicted_tflops = res["tflops"] * e.scale
-
-    report = FleetReport(entries=entries, bucket=bucket,
-                         compiles=compiles, tuning=tuning, metrics=m)
+    with phase("report"):
+        for e, res in zip(entries, results):
+            e.predicted_tflops = res["tflops"] * e.scale
+        report = FleetReport(entries=entries, bucket=bucket,
+                             compiles=compiles, tuning=tuning, metrics=m)
+        if m.enabled:
+            m.counter("fleet.machines").inc(len(entries))
+            m.counter("fleet.compiles").inc(compiles)
+            # tallied first: one counter update per source, not per
+            # machine and source (~0.9 ms a 51-machine wave otherwise)
+            sources = collections.Counter(
+                src for e in entries for src, _ in e.platform.provenance)
+            for src, n in sources.items():
+                m.counter("fleet.provenance", source=src).inc(n)
     if calibrate:
         from .calibrate import calibrate_fleet
-        t0 = _time.perf_counter()
-        report.calibration = calibrate_fleet(entries)
+        with phase("calibrate"):
+            report.calibration = calibrate_fleet(entries)
         if m.enabled:
-            m.histogram("fleet.phase_wall_s", phase="calibrate").observe(
-                _time.perf_counter() - t0)
             for fam, f in sorted(report.calibration.factors.items()):
                 m.gauge("fleet.calibration_factor", family=fam).set(f)
     return report

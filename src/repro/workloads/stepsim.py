@@ -23,17 +23,17 @@ cross-validation holds the same way DES-vs-fastsim does for HPL.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
-import time
 from typing import Dict, List, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.fastsim import _pad_lanes, _record_shard, _shard_lanes
-from repro.obs.metrics import RATIO_BUCKETS, get_global_metrics
+from repro.core.fastsim import _call, _pad_lanes, _shard_lanes
+from repro.obs.metrics import Timer, get_global_metrics
 
 
 @dataclasses.dataclass(frozen=True)
@@ -117,11 +117,11 @@ def trace_count() -> int:
 
 @functools.lru_cache(maxsize=4)
 def _compiled():
-    def fn(p):
+    def transformer_step(p):
         global _TRACE_COUNT
         _TRACE_COUNT += 1
         return _step_core(p)
-    return jax.jit(fn)
+    return jax.jit(transformer_step)
 
 
 def step_time_traced(p: StepParams):
@@ -156,34 +156,21 @@ def sweep_step(params_list: Sequence[StepParams]) -> List[Dict]:
     prm_list = [_f64_step_params(p) for p in params_list]
     if not prm_list:
         return []
-    lanes = _pad_lanes(list(range(len(prm_list))))
     m = get_global_metrics()
+    pre = trace_count()
     with jax.enable_x64(True):
-        fn = _compiled()
-        (stacked,), sharded = _shard_lanes(
-            len(lanes), _stack_step_params(prm_list, lanes))
-        if m.enabled:
-            pre, t0 = trace_count(), time.perf_counter()
-        out = np.asarray(fn(stacked))
-        if m.enabled:
-            # same taxonomy as fastsim._record_dispatch, one shared
-            # "step" bucket (the step core is shape-monomorphic)
-            dt = time.perf_counter() - t0
-            misses = trace_count() - pre
-            if misses:
-                m.counter("stepsim.compile_misses", bucket="step").inc(
-                    misses)
-                m.histogram("stepsim.compile_wall_s",
-                            bucket="step").observe(dt)
-            else:
-                m.counter("stepsim.compile_hits", bucket="step").inc()
-                m.histogram("stepsim.dispatch_wall_s").observe(dt)
-            m.counter("stepsim.lanes_live").inc(len(prm_list))
-            m.counter("stepsim.lanes_padded").inc(
-                len(lanes) - len(prm_list))
-            m.histogram("stepsim.sweep_occupancy", RATIO_BUCKETS).observe(
-                len(prm_list) / len(lanes))
-            _record_shard(m, sharded, prefix="stepsim")
+        with (Timer(span="stepsim.prepare") if m.enabled
+              else contextlib.nullcontext()) as prep:
+            lanes = _pad_lanes(list(range(len(prm_list))))
+            (stacked,), sharded = _shard_lanes(
+                len(lanes), _stack_step_params(prm_list, lanes))
+        # fastsim's dispatch taxonomy under the stepsim.* prefix, one
+        # shared "step" bucket (the step core is shape-monomorphic)
+        out = _call(_compiled(), (stacked,), ("step",), len(prm_list),
+                    len(lanes), sharded, prefix="stepsim",
+                    traces=trace_count)
+    if m.enabled and trace_count() == pre:
+        m.histogram("stepsim.prepare_s").observe(prep.elapsed)
     return [_result(p, float(t))
             for p, t in zip(prm_list, out[:len(prm_list)])]
 
