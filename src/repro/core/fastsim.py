@@ -8,9 +8,13 @@ per-panel timing recurrence is a max-plus system over the P x Q grid:
                    ring: a_i = hop*i + cummax_j<=i (d_j - hop*j)
   T_{k+1}(p,q)     = max(T_k, arrival, colmax(arrival)) + swap + update
 
-Everything is vectorized over the grid and the panel loop is a
-``lax.fori_loop`` — Frontera's 48k panels x 8,008 ranks simulate in
-seconds on this laptop-class CPU (cross-validated against the DES path in
+Everything is vectorized over the grid.  The panel loop runs in blocks
+of up to 256 panels: a block first builds, as tables in one vectorised
+pass, everything its panels need that does not depend on the timing
+state (widths, NUMROC counts, broadcast hops, trsm, swap and
+factorization times), then a serial loop steps only the state-dependent
+max-plus chain.  Frontera's 24k panels x 8,008 ranks simulate in seconds
+on a laptop-class CPU (cross-validated against the DES path in
 tests/test_hpl_sim.py).
 
 Beyond single runs, this module is a *batched sweep engine* (DESIGN.md
@@ -99,16 +103,43 @@ def bucket_key(cfg: HPLConfig) -> Tuple[int, int, int]:
 
 
 # ------------------------------------------------------------ traced core
+# most panels per block of _sim_core (128, 256 and 512 ran Frontera's
+# recurrence within 3% of each other on a TPU v5e)
+_BLOCK = 256
+
+
+def _block_size(n_panels_max: int) -> int:
+    """Panels per block for a panel bucket: the whole bucket up to
+    ``_BLOCK`` panels, else the largest power of two <= ``_BLOCK`` that
+    divides it (buckets are 2^k or 3*2^(k-1): 256, or 128 at 384)."""
+    if n_panels_max <= _BLOCK:
+        return n_panels_max
+    return math.gcd(n_panels_max, _BLOCK)
+
+
 def _sim_core(N, nb, P, Q, prm: FastSimParams,
               n_panels_max: int, P_max: int, Q_max: int):
     """HPL panel recurrence with *traced* (N, nb, P, Q, prm).
 
     Shapes are the static bucket (P_max, Q_max) and the loop runs
-    n_panels_max iterations; rows p >= P, columns q >= Q and panels
+    n_panels_max panels; rows p >= P, columns q >= Q and panels
     k >= ceil(N/nb) are padding, masked so they never touch live lanes (the
     ring-broadcast permutation maps padding columns to themselves, the
     column-sync max and the final max are mask-reduced, and the loop
     carry freezes once k reaches the live panel count).
+
+    The panels run in blocks of C = ``_block_size(n_panels_max)``.  Each
+    block first builds, in one vectorised pass (named scope
+    ``hpl.tables``), everything its C panels need that does not read the
+    carry: widths, NUMROC row and column counts, broadcast hops, trsm,
+    swap and next-panel factorization times, the lookahead gemm and the
+    live flag, each a table with a leading axis of C.  A serial loop of C
+    steps then runs only the chain that reads the carry ``(T,
+    fact_done)``: ring arrival (prefix max), the update's gemm from the
+    tables' row and column counts, column sync, the next panel's
+    factorization anchor, the freeze and the ring re-base.  Every value
+    is computed by the same float64 operations in the same order as a
+    single per-panel step would.
 
     ``prm`` leaves are (B,)-vectors: the whole recurrence carries a
     *trailing* scenario-batch axis — grid state is (P_max, Q_max, B) —
@@ -144,36 +175,85 @@ def _sim_core(N, nb, P, Q, prm: FastSimParams,
     row_on = jnp.arange(P_max) < P
     col_on = jnp.arange(Q_max) < Q
     active = row_on[:, None] & col_on[None, :]
-    # ceil: a trailing N % nb panel is simulated at its true width
-    n_panels = (N + nb - 1) // nb
     iq = jnp.arange(Q_max)
 
-    def width(rem):
-        """Panel width: nb except on the trailing partial panel (and 0 on
-        padding iterations past the live panel count)."""
-        return jnp.clip(jnp.minimum(nb, rem), 0)
+    # whole nb-blocks and trailing columns of the matrix: panel k has
+    # max(n_blocks - k, 0) whole blocks and the tail (while k <= n_blocks)
+    # left, so the per-panel tables divide only by P and Q
+    n_blocks = N // nb
+    n_tail = N - n_blocks * nb
+    # ceil: a trailing N % nb panel is simulated at its true width
+    n_panels = n_blocks + (n_tail > 0)
 
-    def numroc_vec(rem, shift, nprocs, size):
-        """Vectorized NUMROC for procs 0..size-1 with owner shift."""
-        ip = (jnp.arange(size) - shift) % nprocs
-        nblocks = rem // nb
-        base = (nblocks // nprocs) * nb
-        extra = nblocks % nprocs
-        return (base + jnp.where(ip < extra, nb,
-                                 jnp.where(ip == extra, rem % nb, 0))
+    def numroc_vec(blocks, tail, shift, nprocs, size):
+        """Vectorized NUMROC for procs 0..size-1 of panels with ``blocks``
+        whole blocks and ``tail`` columns left (K,), dealt from owner
+        ``shift`` ((K,) or scalar, in [0, nprocs)): (K, size)."""
+        ip = jnp.arange(size) - jnp.reshape(shift, (-1, 1))
+        ip = jnp.where(ip < 0, ip + nprocs, ip)  # (p - shift) % nprocs, p live
+        blocks = blocks[:, None]
+        per = blocks // nprocs
+        extra = blocks - per * nprocs
+        return (per * nb + jnp.where(ip < extra, nb,
+                                     jnp.where(ip == extra, tail[:, None],
+                                               0))
                 ).astype(f64)
 
-    def fact_time(k):
-        """Panel-k factorization cost per row rank (SimBLAS closed forms):
-        dger/dscal/idamax are Level-1/2 memory-bound.  Returns (P, B)."""
+    def geometry(ks):
+        """Per panel of ``ks`` (K,): whole blocks and tail left, width
+        (K,) and local rows (K, P)."""
+        blocks = jnp.maximum(n_blocks - ks, 0)
+        tail = jnp.where(ks <= n_blocks, n_tail, 0)
+        # nb except on the trailing partial panel, 0 past the live panels
+        width = jnp.where(blocks > 0, nb, tail)
+        owner = ks - ks // P * P
+        return (blocks, tail, width.astype(f64),
+                numroc_vec(blocks, tail, owner, P, P_max))
+
+    def fact_time(wf, mloc):
+        """Factorization cost per row rank of panels of width ``wf`` (K,)
+        over ``mloc`` (K, P) local rows (SimBLAS closed forms):
+        dger/dscal/idamax are Level-1/2 memory-bound.  Returns (K, P, B)."""
         with jax.named_scope("hpl.fact"):
-            rem = N - k * nb
-            wf = width(rem).astype(f64)
-            mloc = numroc_vec(rem, k % P, P, P_max)
+            wf = wf[:, None]
             pf_bytes = 8.0 * (jnp.maximum(mloc * wf * wf - wf ** 3 / 3.0,
                                           0.0) + 3.0 * mloc * wf)
-            return (pf_bytes[:, None] / mem_bw + wf * (3 * theta)
+            wf = wf[:, :, None]
+            return (pf_bytes[:, :, None] / mem_bw + wf * (3 * theta)
                     + wf * ar_lat)
+
+    C = _block_size(n_panels_max)
+
+    def tables(k0):
+        """What panels k0 .. k0+C-1 need that does not read the carry,
+        each with a leading axis of C."""
+        # one geometry pass over the block's panels and the next one:
+        # panel k+1's widths and rows feed panel k's lookahead
+        ks = k0 + jnp.arange(C + 1)
+        blocks, tail, w_all, mloc_all = geometry(ks)
+        wf, mloc = w_all[:C], mloc_all[:C]                   # (C,), (C, P)
+        w_next, mloc_n = w_all[1:], mloc_all[1:]
+        # the update's columns: those left after the panel, ord space
+        nloc = numroc_vec(blocks[1:], tail[1:], 1, Q, Q_max)     # (C, Q)
+        w = wf[:, None]
+        panel_bytes = 8.0 * (mloc + w) * w                       # (C, P)
+        tb = {"wf": wf, "mloc": mloc, "nloc": nloc,
+              "hop": alpha + panel_bytes[:, :, None] / bcast_bw,  # (C, P, B)
+              "trsm": (w * w * nloc)[:, :, None] / peak + theta,  # (C, Q, B)
+              "ft": fact_time(w_next, mloc_n),                   # (C, P, B)
+              "gemm_nb": (2.0 * mloc_n[:, :, None] * w_next[:, None, None]
+                          * wf[:, None, None]) / peak + theta,   # (C, P, B)
+              "live": ks[:C] < n_panels}
+        if P_max > 1:
+            u_bytes = 8.0 * w * nloc                             # (C, Q)
+            tb["swap"] = jnp.where(
+                u_bytes[:, :, None] > 0,
+                sw_rounds * (alpha + (u_bytes[:, :, None]
+                                      / jnp.maximum(sw_rounds, 1.0))
+                             / swap_bw)
+                + (4.0 * 8.0 * w * nloc)[:, :, None] / mem_bw,
+                0.0)                                             # (C, Q, B)
+        return tb
 
     # The T carry lives in *ring-order* space: stored column i holds the
     # absolute column (qk + i) % Q, so the broadcast root is always index
@@ -191,6 +271,9 @@ def _sim_core(N, nb, P, Q, prm: FastSimParams,
     # bucket(1) == 1, so Q_max > 1 implies Q >= 2: the ord index of
     # column (k+1) % Q — i.e. 1 % Q — is static.
     idx1 = 1 if Q_max > 1 else 0
+    qcol = iq[None, :, None]
+    roll_on = qcol < Q - 1
+    root_on = qcol == Q - 1
 
     def cummax_cols(x):
         """Inclusive prefix-max along axis 1 (Kogge-Stone shift-max)."""
@@ -209,78 +292,58 @@ def _sim_core(N, nb, P, Q, prm: FastSimParams,
         if Q_max == 1:
             return T
         roll = jnp.concatenate([T[:, 1:, :], T[:, :1, :]], axis=1)
-        qcol = iq[None, :, None]
         return jnp.where(
-            qcol < Q - 1, roll,
-            jnp.where(qcol == Q - 1,
-                      jnp.broadcast_to(T[:, :1, :], T.shape), T))
+            roll_on, roll,
+            jnp.where(root_on, jnp.broadcast_to(T[:, :1, :], T.shape), T))
 
-    def step(k, T, fact_done):
-        # each phase's ops carry a named scope (hpl.fact, hpl.bcast,
-        # hpl.swap, hpl.update, hpl.lookahead), so a profile sorts the
-        # ops of a step by phase
-        rem = N - k * nb
-        wf = width(rem).astype(f64)                      # panel width
-        mloc = numroc_vec(rem, k % P, P, P_max)                    # (P,)
-        nloc = numroc_vec(jnp.maximum(rem - width(rem), 0), 1, Q,
-                          Q_max)                                   # (Q,) ord
+    def chain(carry, tb):
+        """One panel's carry-dependent step, given its tables ``tb``."""
+        # each phase's ops carry a named scope (hpl.bcast, hpl.swap,
+        # hpl.update, hpl.lookahead), so a profile sorts the ops of a
+        # step by phase
+        T, fact_done = carry
+        wf, mloc, nloc = tb["wf"], tb["mloc"], tb["nloc"]
 
         # 2. 1-ring broadcast along each row: prefix-max recurrence.
         # fact_done was computed in the previous iteration (lookahead):
         # the owning column factored panel k right after updating the
         # panel-k columns of step k-1, overlapping the rest of the update.
         with jax.named_scope("hpl.bcast"):
-            panel_bytes = 8.0 * (mloc + wf) * wf         # (P,)
-            hop = alpha + panel_bytes[:, None] / bcast_bw    # (P, B)
-            hi = hop[:, None, :] * iq.astype(f64)[None, :, None]
+            hi = tb["hop"][:, None, :] * iq.astype(f64)[None, :, None]
             d = (T - hi).at[:, 0, :].set(fact_done)      # chain readiness
             a = hi + cummax_cols(d)
             arrival = a.at[:, 0, :].set(fact_done)       # root holds panel
 
         # 4. update: dtrsm + dgemm on the local tile
         with jax.named_scope("hpl.update"):
-            trsm = (wf * wf * nloc)[:, None] / peak + theta    # (Q, B)
             gemm = (2.0 * mloc[:, None, None] * nloc[None, :, None] * wf
                     + 2.0 * mloc[:, None, None] * nloc[None, :, None]) \
                 / peak + theta                           # (P, Q, B)
         # 3. row swaps: column ranks exchange the U strip (sync on colmax)
         if P_max > 1:                    # P > 1 exactly (bucket(1) == 1)
             with jax.named_scope("hpl.swap"):
-                u_bytes = 8.0 * wf * nloc                # (Q,)
-                swap = jnp.where(
-                    u_bytes[:, None] > 0,
-                    sw_rounds * (alpha + (u_bytes[:, None]
-                                          / jnp.maximum(sw_rounds, 1.0))
-                                 / swap_bw)
-                    + (4.0 * 8.0 * wf * nloc)[:, None] / mem_bw,
-                    0.0)                                 # (Q, B)
                 # column sync: every rank of a column proceeds from the
                 # column max, so after_swap is row-independent — a (Q, B)
                 # row vector instead of a (P, Q, B) grid.
                 colmax = jnp.max(jnp.maximum(arrival, T), axis=0,
                                  where=row_on[:, None, None],
                                  initial=-jnp.inf)       # (Q, B)
-                after_swap = colmax + swap               # (Q, B)
+                after_swap = colmax + tb["swap"]         # (Q, B)
             with jax.named_scope("hpl.update"):
-                T_new = (after_swap + trsm)[None, :, :] + gemm
+                T_new = (after_swap + tb["trsm"])[None, :, :] + gemm
             as_next = after_swap[idx1]                   # (B,) static slice
         else:
             with jax.named_scope("hpl.swap"):
                 after_swap = jnp.maximum(arrival, T)     # (1, Q, B)
             with jax.named_scope("hpl.update"):
-                T_new = after_swap + trsm[None, :, :] + gemm
+                T_new = after_swap + tb["trsm"][None, :, :] + gemm
             as_next = after_swap[:, idx1, :]             # (P=1, B)
 
         # 1'. (lookahead) factor panel k+1 on its owning column, anchored
         # right after that column updates just the next panel's columns.
-        ft = fact_time(k + 1)
         with jax.named_scope("hpl.lookahead"):
-            mloc_n = numroc_vec(jnp.maximum(rem - nb, 0), (k + 1) % P, P,
-                                P_max)
-            w_next = width(rem - nb).astype(f64)
-            gemm_nb = (2.0 * mloc_n[:, None] * w_next * wf) / peak \
-                + theta                                             # (P, B)
-            fact_next_overlap = as_next + gemm_nb + ft
+            ft = tb["ft"]
+            fact_next_overlap = as_next + tb["gemm_nb"] + ft
             fact_next_serial = T_new[:, idx1, :] + ft
             fact_next = (lookahead * jnp.minimum(fact_next_overlap,
                                                  fact_next_serial)
@@ -288,20 +351,25 @@ def _sim_core(N, nb, P, Q, prm: FastSimParams,
         # the panel column cannot broadcast before finishing its own step
         # only when overlapping is off; with lookahead the bcast may start
         # mid-update (HPL posts it asynchronously).
-        return T_new, fact_next
-
-    def body(k, carry):
-        T, F = carry
-        T2, F2 = step(k, T, F)
-        live = k < n_panels
+        #
         # freeze once past the live panel count, then re-base the ring
         # (frozen values must keep rotating with qk to stay column-stable;
         # the final masked max is invariant under the live-column cycle)
-        return ring_rebase(jnp.where(live, T2, T)), jnp.where(live, F2, F)
+        live = tb["live"]
+        return (ring_rebase(jnp.where(live, T_new, T)),
+                jnp.where(live, fact_next, fact_done)), None
+
+    def block(j, carry):
+        with jax.named_scope("hpl.tables"):
+            tb = tables(j * C)
+        return jax.lax.scan(chain, carry, tb)[0]
 
     T0 = jnp.zeros((P_max, Q_max, B), f64)
-    F0 = fact_time(0)                    # panel 0: nothing to overlap with
-    T, _ = jax.lax.fori_loop(0, n_panels_max, body, (T0, F0))
+    with jax.named_scope("hpl.tables"):
+        # panel 0: nothing to overlap with
+        _, _, w0, mloc0 = geometry(jnp.zeros(1, jnp.int64))
+        F0 = fact_time(w0, mloc0)[0]
+    T, _ = jax.lax.fori_loop(0, n_panels_max // C, block, (T0, F0))
     total = jnp.max(jnp.where(active[:, :, None], T, -jnp.inf),
                     axis=(0, 1))                         # (B,)
     # back substitution: ~2 N^2 flops + N broadcasts (minor)

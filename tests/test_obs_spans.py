@@ -183,7 +183,8 @@ def test_programs_and_panel_phases_carry_stable_names():
             text = fastsim._compiled(7, 3, 5, mode).lower(*g, p).as_text(
                 debug_info=True)
             assert f"module @jit_hpl_recurrence_{mode}" in text
-            for phase in ("fact", "bcast", "swap", "update", "lookahead"):
+            for phase in ("tables", "fact", "bcast", "swap", "update",
+                          "lookahead"):
                 assert f"hpl.{phase}/" in text, (mode, phase)
         sp = stepsim._stack_step_params(
             [stepsim.StepParams(peak_flops=1e12, gemm_eff=0.5, mem_bw=1e9,

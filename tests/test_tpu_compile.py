@@ -4,7 +4,9 @@ The TPU compiler refuses what interpret mode and the CPU backend accept
 (block shapes off the (8, 128) tiling, mask relayouts), so the three
 Pallas kernels are compiled here at the widths of the models they serve,
 together with the stepsim core and the fastsim ``params`` core at its
-smallest bucket (the compile time does not depend on the bucket).
+smallest bucket, one block of panels, and at a bucket of two blocks,
+where the serial panel loop nests inside the loop over blocks (the
+compile time does not depend on the bucket otherwise).
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and it keeps it until it exits, so
@@ -99,11 +101,24 @@ def test_stepsim_core_compiles_in_float64(spec):
     assert c.memory_analysis() is not None
 
 
-def test_fastsim_params_core_compiles_in_float64(spec):
+def _fastsim_params_core(spec, n_panels_max):
     from repro.core import fastsim
     with jax.enable_x64(True):
         prm = fastsim.FastSimParams(**{n: spec((8,), jnp.float64)
                                        for n in fastsim._PARAM_FIELDS})
         geom = [spec((), jnp.int64)] * 4
-        c = fastsim._compiled(32, 4, 4, "params").lower(*geom, prm).compile()
+        return fastsim._compiled(n_panels_max, 4, 4, "params").lower(
+            *geom, prm).compile()
+
+
+def test_fastsim_params_core_compiles_in_float64(spec):
+    c = _fastsim_params_core(spec, 32)
+    assert c.memory_analysis() is not None
+
+
+def test_fastsim_params_core_compiles_in_blocks(spec):
+    # 512 panels: two blocks of 256, the block loop around the panel loop
+    from repro.core import fastsim
+    assert fastsim._block_size(512) == 256
+    c = _fastsim_params_core(spec, 512)
     assert c.memory_analysis() is not None
