@@ -31,6 +31,10 @@ from repro.core.simblas import SimBLAS
 from repro.core.simmpi import SimMPI
 
 
+#: HPL.dat's process mappings (see ``HPLConfig.pmap``)
+PMAPS = ("col", "row")
+
+
 def numroc(n: int, nb: int, iproc: int, nprocs: int) -> int:
     """ScaLAPACK NUMROC: local rows/cols of an n-length dim distributed in
     nb blocks over nprocs, for process iproc (src proc 0)."""
@@ -46,12 +50,22 @@ def numroc(n: int, nb: int, iproc: int, nprocs: int) -> int:
 
 @dataclasses.dataclass
 class HPLConfig:
+    """One HPL run, as HPL.dat states it: problem size ``N``, block size
+    ``nb``, the P x Q process grid, the panel broadcast, the modeled
+    lookahead depth, and ``pmap``, the process mapping that places MPI
+    rank q*P + p ("col") or p*Q + q ("row", HPL.dat's PMAP=0) at grid
+    position (p, q).  With several ranks a node (rank r on node r // R)
+    the mapping decides which of them share a node's network link."""
     N: int
     nb: int
     P: int
     Q: int
     bcast: str = "1ring"          # 1ring | long
     lookahead: int = 0            # modeled depth (0: panel on critical path)
+    # HPL.dat's process mapping: "col" puts rank q*P + p at (p, q), so a
+    # node's consecutive ranks run down a grid column; "row" (HPL.dat's
+    # PMAP=0) puts rank p*Q + q there, so they run along a grid row
+    pmap: str = "col"
 
     def __post_init__(self):
         if self.N < 1 or self.nb < 1:
@@ -63,6 +77,9 @@ class HPLConfig:
         if self.bcast not in ("1ring", "long"):
             raise ValueError(f"HPLConfig: bcast={self.bcast!r} not in "
                              "('1ring', 'long')")
+        if self.pmap not in PMAPS:
+            raise ValueError(f"HPLConfig: pmap={self.pmap!r} not in "
+                             f"{PMAPS}")
         if self.lookahead not in (0, 1):
             raise ValueError(f"HPLConfig: lookahead={self.lookahead} must "
                              "be 0 or 1")
@@ -72,6 +89,16 @@ class HPLConfig:
     @property
     def n_ranks(self) -> int:
         return self.P * self.Q
+
+    def rank_of(self, p: int, q: int) -> int:
+        """The MPI rank at grid position (p, q) under ``pmap``."""
+        return q * self.P + p if self.pmap == "col" else p * self.Q + q
+
+    def position(self, rank: int):
+        """(p, q) of an MPI rank under ``pmap``."""
+        if self.pmap == "col":
+            return rank % self.P, rank // self.P
+        return rank // self.Q, rank % self.Q
 
     @property
     def n_panels(self) -> int:
@@ -104,8 +131,7 @@ class HPLRank:
     def __init__(self, sim: "HPLSim", rank: int):
         self.sim = sim
         self.rank = rank
-        self.p = rank % sim.cfg.P          # row coordinate (column-major grid)
-        self.q = rank // sim.cfg.P
+        self.p, self.q = sim.cfg.position(rank)   # grid row and column
 
     def run(self):
         sim = self.sim
@@ -118,8 +144,8 @@ class HPLRank:
         faen = fa.enabled
         blas = sim.blas[self.rank]
         P, Q, nb, N = cfg.P, cfg.Q, cfg.nb, cfg.N
-        col_group = [self.q * P + pp for pp in range(P)]
-        row_group = [qq * P + self.p for qq in range(Q)]
+        col_group = [cfg.rank_of(pp, self.q) for pp in range(P)]
+        row_group = [cfg.rank_of(self.p, qq) for qq in range(Q)]
         n_panels = cfg.n_panels            # ceil: trailing partial panel
         if sim.max_panels is not None:     # region truncation (scale/)
             n_panels = min(n_panels, sim.max_panels)

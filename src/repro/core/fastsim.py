@@ -5,7 +5,12 @@ per-panel timing recurrence is a max-plus system over the P x Q grid:
 
   fact_k(p)        panel factorization on owning column (SimBLAS closed forms)
   arrival_k(p,q)   1-ring store&forward broadcast = prefix-max along the row
-                   ring: a_i = hop*i + cummax_j<=i (d_j - hop*j)
+                   ring: a_i = H_i + cummax_j<=i (d_j - H_j), where H_i is
+                   the cost of the first i hops from the root: hop*i with one
+                   rank a node; with R ranks a node (``ranks_per_node``)
+                   hop*n_inter(i) + alpha0*n_intra(i), a hop that leaves the
+                   node shared by the node's ranks that forward with it, one
+                   inside the node latency alone (see ``_sim_core``)
   T_{k+1}(p,q)     = max(T_k, arrival, colmax(arrival)) + swap + update
 
 Everything is vectorized over the grid.  The panel loop runs in blocks
@@ -60,31 +65,102 @@ class FastSimParams:
     bcast_bw_scale: float = 1.0  # contention scale on panel broadcast
     swap_bw_scale: float = 1.0   # contention scale on row swaps
     lookahead: float = 1.0       # HPL lookahead depth (1 = overlap panel)
+    # ranks sharing one node and its NIC (the per-rank fields above are
+    # already the rank's share); > 1 selects the node-aware recurrence
+    ranks_per_node: int = 1
+    # latency of a message between two ranks of one node: MPI overhead +
+    # fabric base latency, what the DES charges; the node-aware recurrence
+    # needs it
+    intra_latency: Optional[float] = None
 
     @staticmethod
     def from_node(node: NodeModel, *, link_bw: float,
                   ranks_per_node: int = 1, net_latency: float = 2e-6,
+                  intra_latency: Optional[float] = None,
                   **kw) -> "FastSimParams":
         return FastSimParams(
             peak_flops=node.peak_flops / ranks_per_node,
             gemm_eff=node.gemm_efficiency,
             mem_bw=node.mem_bw * node.mem_efficiency / ranks_per_node,
             theta=node.blas_latency,
-            link_bw=link_bw, net_latency=net_latency, **kw)
+            link_bw=link_bw, net_latency=net_latency,
+            ranks_per_node=ranks_per_node, intra_latency=intra_latency,
+            **kw)
 
 
-_PARAM_FIELDS = tuple(f.name for f in dataclasses.fields(FastSimParams))
+#: fields that reach a program as arguments of their own (``_node_args``)
+_NODE_FIELDS = ("ranks_per_node", "intra_latency")
+#: the traced fields
+_PARAM_FIELDS = tuple(f.name for f in dataclasses.fields(FastSimParams)
+                      if f.name not in _NODE_FIELDS)
 
 # Registered as a pytree: a FastSimParams passed to jit is *traced*, so
 # changing any value reuses the compiled program (the old code passed a
 # dict of Python floats baked in at trace time).
 jax.tree_util.register_dataclass(
-    FastSimParams, data_fields=list(_PARAM_FIELDS), meta_fields=[])
+    FastSimParams, data_fields=list(_PARAM_FIELDS),
+    meta_fields=list(_NODE_FIELDS))
 
 
 def _f64_params(prm: FastSimParams) -> FastSimParams:
     """Normalize leaves to Python floats so the jit cache sees one dtype."""
-    return FastSimParams(**{n: float(getattr(prm, n)) for n in _PARAM_FIELDS})
+    return dataclasses.replace(
+        prm, **{n: float(getattr(prm, n)) for n in _PARAM_FIELDS})
+
+
+def node_block(cfg: HPLConfig,
+               ranks_per_node: int) -> Optional[Tuple[int, int]]:
+    """(rows, columns) of the block of the P x Q grid that one node
+    holds, or None for one rank per node (the node-blind recurrence).
+
+    Node r // R holds R consecutive MPI ranks, which ``cfg.pmap`` lays
+    out down grid columns ("col": rank q*P + p) or along grid rows
+    ("row": rank p*Q + q).  The node-aware recurrence prices its hops by
+    that block, so it answers only where every node holds one: "col"
+    with R | P (R rows of one column) or P | R (R/P whole columns), "row"
+    with R | Q or Q | R; any other mapping raises ``ValueError``, as does
+    a broadcast other than "1ring" (the DES, ``HPLSim``, runs both)."""
+    if cfg.bcast != "1ring":
+        raise ValueError(f"the fast model runs the 1ring broadcast, not "
+                         f"bcast={cfg.bcast!r}: use the DES (HPLSim)")
+    R = int(ranks_per_node)
+    if R < 1:
+        raise ValueError(f"ranks_per_node={R} must be >= 1")
+    if R == 1:
+        return None
+    P, Q = cfg.P, cfg.Q
+    if cfg.pmap == "col":
+        if P % R == 0:
+            return R, 1
+        if R % P == 0:
+            return P, R // P
+    else:
+        if Q % R == 0:
+            return 1, R
+        if R % Q == 0:
+            return R // Q, Q
+    axis, n = ("P", P) if cfg.pmap == "col" else ("Q", Q)
+    raise ValueError(
+        f"{R} ranks per node under pmap={cfg.pmap!r} on a {P} x {Q} grid: "
+        f"the fast model needs {axis}={n} and R to divide one another, so "
+        f"that every node holds a block of the grid; the DES (HPLSim) "
+        f"runs any mapping")
+
+
+def _node_args(cfg: HPLConfig, prm: FastSimParams) -> tuple:
+    """The node-aware program's own arguments for one scenario: the
+    node's block of the grid (``node_block``) and the intra-node latency;
+    () for one rank a node."""
+    block = node_block(cfg, prm.ranks_per_node)
+    if block is None:
+        return ()
+    if prm.intra_latency is None:
+        raise ValueError(
+            f"ranks_per_node={prm.ranks_per_node} needs intra_latency, the "
+            f"latency of a message between two ranks of one node (MPI "
+            f"overhead + fabric base latency, as the DES charges it)")
+    return (np.int64(block[0]), np.int64(block[1]),
+            np.float64(prm.intra_latency))
 
 
 # ------------------------------------------------------------- bucketing
@@ -118,7 +194,7 @@ def _block_size(n_panels_max: int) -> int:
 
 
 def _sim_core(N, nb, P, Q, prm: FastSimParams,
-              n_panels_max: int, P_max: int, Q_max: int):
+              n_panels_max: int, P_max: int, Q_max: int, *nodes):
     """HPL panel recurrence with *traced* (N, nb, P, Q, prm).
 
     Shapes are the static bucket (P_max, Q_max) and the loop runs
@@ -148,6 +224,26 @@ def _sim_core(N, nb, P, Q, prm: FastSimParams,
     axis would make every gather element-strided; measured ~4x slower).
     Geometry (N, nb, P, Q) is scalar per call; mixed-geometry sweeps
     vmap over this core with B=1 (see ``_compiled``).
+
+    ``nodes``, where given, is the traced ``(rows, cols, alpha0)``: the
+    block of the grid that one node holds (``node_block``) and the
+    latency of a message inside a node, (B,) (``intra_latency``); the
+    program is then the node-aware one (named scope ``hpl.nodes`` in the
+    tables).  A broadcast hop into a column that starts a node's block
+    leaves the node: it costs ``alpha`` plus the panel's bytes over the
+    link, shared by the block's ``rows`` ranks, which forward through the
+    one NIC at about the same time; a hop inside the block costs
+    ``alpha0`` (MPI overhead + fabric base latency) with no bandwidth
+    term, as the DES charges a message between two ranks of one node.
+    The ring's prefix sum of hop costs, ``hop * i`` on one rank a node,
+    becomes ``hop * n_inter + alpha0 * n_intra``, where
+    ``n_inter`` counts the node-leaving hops among the first i from the
+    root.  A swap round ends on its slowest pair: a pair that crosses
+    nodes exists where a node holds fewer rows than the column, and then
+    moves its bytes over the link shared by the block's ``cols`` ranks;
+    a pair inside a node of several rows costs ``alpha0``, which a
+    what-if of the network's latency alone can make the larger.  With
+    a block of (1, 1) every value is the node-blind one, bitwise.
     """
     f64 = jnp.float64
     N = jnp.asarray(N, jnp.int64)
@@ -171,6 +267,17 @@ def _sim_core(N, nb, P, Q, prm: FastSimParams,
                        else 0.0 for p in range(P_max + 1)], f64)
     ar_lat = ar2[P] * alpha                              # (B,)
     sw_rounds = swr[P]
+
+    if nodes:
+        node_rows, node_cols = (jnp.asarray(g, jnp.int64)
+                                for g in nodes[:2])
+        alpha0 = jnp.asarray(nodes[2], f64)                      # (B,)
+        # a swap pair leaves its node only if a node holds part of a column
+        sw_cross = node_rows < P
+        swap_lat = jnp.where(sw_cross, alpha, alpha0)            # (B,)
+        swap_share = jnp.where(sw_cross, node_cols, 0).astype(f64)
+        # a node of several rows holds pairs that stay on it, at alpha0
+        swap_floor = jnp.where(node_rows > 1, alpha0, 0.0)       # (B,)
 
     row_on = jnp.arange(P_max) < P
     col_on = jnp.arange(Q_max) < Q
@@ -237,6 +344,9 @@ def _sim_core(N, nb, P, Q, prm: FastSimParams,
         nloc = numroc_vec(blocks[1:], tail[1:], 1, Q, Q_max)     # (C, Q)
         w = wf[:, None]
         panel_bytes = 8.0 * (mloc + w) * w                       # (C, P)
+        if nodes:
+            # the block's rows share the NIC on a node-leaving hop
+            panel_bytes = panel_bytes * node_rows.astype(f64)
         tb = {"wf": wf, "mloc": mloc, "nloc": nloc,
               "hop": alpha + panel_bytes[:, :, None] / bcast_bw,  # (C, P, B)
               "trsm": (w * w * nloc)[:, :, None] / peak + theta,  # (C, Q, B)
@@ -246,14 +356,47 @@ def _sim_core(N, nb, P, Q, prm: FastSimParams,
               "live": ks[:C] < n_panels}
         if P_max > 1:
             u_bytes = 8.0 * w * nloc                             # (C, Q)
-            tb["swap"] = jnp.where(
-                u_bytes[:, :, None] > 0,
-                sw_rounds * (alpha + (u_bytes[:, :, None]
-                                      / jnp.maximum(sw_rounds, 1.0))
-                             / swap_bw)
-                + (4.0 * 8.0 * w * nloc)[:, :, None] / mem_bw,
-                0.0)                                             # (C, Q, B)
+            if not nodes:
+                tb["swap"] = jnp.where(
+                    u_bytes[:, :, None] > 0,
+                    sw_rounds * (alpha + (u_bytes[:, :, None]
+                                          / jnp.maximum(sw_rounds, 1.0))
+                                 / swap_bw)
+                    + (4.0 * 8.0 * w * nloc)[:, :, None] / mem_bw,
+                    0.0)                                         # (C, Q, B)
+            else:
+                # a round's pairs that cross nodes share the NIC
+                tb["swap"] = jnp.where(
+                    u_bytes[:, :, None] > 0,
+                    sw_rounds * jnp.maximum(
+                        swap_lat + (u_bytes[:, :, None]
+                                    / jnp.maximum(sw_rounds, 1.0)
+                                    * swap_share) / swap_bw, swap_floor)
+                    + (4.0 * 8.0 * w * nloc)[:, :, None] / mem_bw,
+                    0.0)                                         # (C, Q, B)
+        if nodes:
+            with jax.named_scope("hpl.nodes"):
+                tb.update(ring_hops(ks[:C]))
         return tb
+
+    def ring_hops(ks):
+        """Node-leaving hops among the first i of each panel's ring from
+        its root qk = k % Q (the hops reach absolute columns qk+1 ..
+        qk+i mod Q, and one leaves a node where it reaches a multiple of
+        the block's width), as a (K, Q_max) table, and the latency of the
+        hops that stay on a node (K, Q_max, B)."""
+        qk = ks - ks // Q * Q
+        t = qk[:, None] + iq[None, :]
+
+        def upto(x):                     # multiples of node_cols in [0, x]
+            return jnp.where(x >= 0, x // node_cols + 1, 0)
+        n_inter = (upto(jnp.minimum(t, Q - 1)) - upto(qk)[:, None]
+                   + jnp.where(t >= Q, upto(t - Q), 0))
+        # a node holding whole rows keeps the ring, its wrap included
+        n_inter = jnp.where(node_cols < Q, n_inter, 0).astype(f64)
+        n_intra = iq.astype(f64)[None, :] - n_inter
+        return {"n_inter": n_inter,
+                "intra": n_intra[:, :, None] * alpha0[None, None, :]}
 
     # The T carry lives in *ring-order* space: stored column i holds the
     # absolute column (qk + i) % Q, so the broadcast root is always index
@@ -309,7 +452,11 @@ def _sim_core(N, nb, P, Q, prm: FastSimParams,
         # the owning column factored panel k right after updating the
         # panel-k columns of step k-1, overlapping the rest of the update.
         with jax.named_scope("hpl.bcast"):
-            hi = tb["hop"][:, None, :] * iq.astype(f64)[None, :, None]
+            if not nodes:
+                hi = tb["hop"][:, None, :] * iq.astype(f64)[None, :, None]
+            else:
+                hi = (tb["hop"][:, None, :] * tb["n_inter"][None, :, None]
+                      + tb["intra"][None, :, :])
             d = (T - hi).at[:, 0, :].set(fact_done)      # chain readiness
             a = hi + cummax_cols(d)
             arrival = a.at[:, 0, :].set(fact_done)       # root holds panel
@@ -459,32 +606,45 @@ def trace_count() -> int:
 
 
 def _sim_core_scalar(N, nb, P, Q, prm: FastSimParams,
-                     n_panels_max: int, P_max: int, Q_max: int):
-    """Scalar-params entry over the trailing-batch core (B=1)."""
-    prm1 = jax.tree_util.tree_map(
-        lambda x: jnp.asarray(x, jnp.float64)[None], prm)
-    return _sim_core(N, nb, P, Q, prm1, n_panels_max, P_max, Q_max)[0]
+                     n_panels_max: int, P_max: int, Q_max: int, *nodes):
+    """Scalar-params entry over the trailing-batch core (B=1); ``nodes``
+    as ``_sim_core``'s, with a scalar latency."""
+    def lane(x):
+        return jnp.asarray(x, jnp.float64)[None]
+    prm1 = jax.tree_util.tree_map(lane, prm)
+    nodes1 = nodes[:2] + tuple(map(lane, nodes[2:]))
+    return _sim_core(N, nb, P, Q, prm1, n_panels_max, P_max, Q_max,
+                     *nodes1)[0]
 
 
 @functools.lru_cache(maxsize=128)
-def _compiled(n_panels_max: int, P_max: int, Q_max: int, mode: str):
+def _compiled(n_panels_max: int, P_max: int, Q_max: int, mode: str,
+              nodes: bool = False):
     """mode: 'single' (scalar in/out) | 'params' (shared geometry, (B,)
     params leaves — the trailing-batch fast path for what-if grids) |
-    'batch' (vmap over geometry and params for mixed-config sweeps)."""
+    'batch' (vmap over geometry and params for mixed-config sweeps).
+
+    ``nodes`` compiles the node-aware program, which takes
+    ``_node_args``' (rows, cols, alpha0) after the params: the block per
+    call in 'single' and 'params' mode and per lane in 'batch' mode, the
+    latency per lane; the node-blind program takes no such argument."""
     core = _sim_core if mode == "params" else _sim_core_scalar
 
-    def fn(N, nb, P, Q, prm):
+    def fn(N, nb, P, Q, prm, *node_args):
         global _TRACE_COUNT
         _TRACE_COUNT += 1
-        return core(N, nb, P, Q, prm, n_panels_max, P_max, Q_max)
-    # the program's name in HLO and profiles: jit_hpl_recurrence_<mode>
-    fn.__name__ = fn.__qualname__ = f"hpl_recurrence_{mode}"
+        return core(N, nb, P, Q, prm, n_panels_max, P_max, Q_max,
+                    *node_args)
+    # the program's name in HLO and profiles: jit_hpl_recurrence_<mode>,
+    # or jit_hpl_recurrence_nodes_<mode>
+    fn.__name__ = fn.__qualname__ = \
+        f"hpl_recurrence_{'nodes_' if nodes else ''}{mode}"
     return jax.jit(jax.vmap(fn) if mode == "batch" else fn)
 
 
 def _call(fn, args, key: Tuple, live: int, lanes: int,
           sharded: bool = False, prefix: str = "fastsim",
-          traces=trace_count) -> np.ndarray:
+          traces=trace_count, node_aware: bool = False) -> np.ndarray:
     """Run one compiled program and bring its answer to the host.
 
     With the global metrics registry on, the call is two spans on the
@@ -493,8 +653,9 @@ def _call(fn, args, key: Tuple, live: int, lanes: int,
     ``<prefix>.wait`` (until the answer is on the host).  The dispatch is
     recorded per shape bucket ``key`` as a compile-cache hit or miss
     (``traces`` is the model's trace counter), with its lane occupancy
-    — padding lanes are pure waste — and, on hits only, the launch, wait
-    and whole-dispatch wall times (a miss's wall is the compile's)."""
+    — padding lanes are pure waste — the live lanes that ran the
+    node-aware program (``node_aware``), and, on hits only, the launch,
+    wait and whole-dispatch wall times (a miss's wall is the compile's)."""
     m = get_global_metrics()
     if not m.enabled:
         return np.asarray(fn(*args))
@@ -516,19 +677,27 @@ def _call(fn, args, key: Tuple, live: int, lanes: int,
         m.histogram(f"{prefix}.dispatch_wall_s").observe(dt)
     m.counter(f"{prefix}.lanes_live").inc(live)
     m.counter(f"{prefix}.lanes_padded").inc(lanes - live)
+    if node_aware:
+        m.counter(f"{prefix}.lanes_node_aware").inc(live)
     _record_shard(m, sharded, prefix)
     return out
 
 
-def _single_args(cfg: HPLConfig, prm: FastSimParams) -> tuple:
+def _single_args(cfg: HPLConfig, prm: FastSimParams, nodes: tuple) -> tuple:
+    # every node layout shares one program: the node fields ride as
+    # arguments of their own (``nodes``, from ``_node_args``)
     return (np.int64(cfg.N), np.int64(cfg.nb), np.int64(cfg.P),
-            np.int64(cfg.Q), _f64_params(prm))
+            np.int64(cfg.Q),
+            dataclasses.replace(_f64_params(prm), ranks_per_node=1,
+                                intra_latency=None)) + nodes
 
 
 def _run_single(cfg: HPLConfig, prm: FastSimParams) -> float:
     key = bucket_key(cfg)
-    return float(_call(_compiled(*key, "single"), _single_args(cfg, prm),
-                       key, 1, 1))
+    nodes = _node_args(cfg, prm)
+    return float(_call(_compiled(*key, "single", bool(nodes)),
+                       _single_args(cfg, prm, nodes), key, 1, 1,
+                       node_aware=bool(nodes)))
 
 
 def _stack_params(prm_list: Sequence[FastSimParams],
@@ -557,7 +726,7 @@ def simulate_time_traced(cfg: HPLConfig, prm: FastSimParams):
     is the autodiff surface used by ``calibrate.fit_fastsim_params``."""
     return _sim_core_scalar(np.int64(cfg.N), np.int64(cfg.nb),
                             np.int64(cfg.P), np.int64(cfg.Q), prm,
-                            *bucket_key(cfg))
+                            *bucket_key(cfg), *_node_args(cfg, prm))
 
 
 def _result(cfg: HPLConfig, t: float) -> dict:
@@ -612,10 +781,12 @@ def sweep_hpl(configs: Configs, params: Params, *,
     with jax.enable_x64(True):
         with (Timer(span="fastsim.prepare") if m.enabled
               else contextlib.nullcontext()) as prep:
-            plan = (_plan(cfg_list, prm_list) if bucket is None
-                    else _plan_forced(cfg_list, prm_list, bucket))
-        for fn, args, idxs, key, lanes, sharded in plan:
-            out = _call(fn, args, key, len(idxs), lanes, sharded)
+            nodes = [_node_args(c, p) for c, p in zip(cfg_list, prm_list)]
+            plan = (_plan(cfg_list, prm_list, nodes) if bucket is None
+                    else _plan_forced(cfg_list, prm_list, nodes, bucket))
+        for fn, args, idxs, key, lanes, sharded, aware in plan:
+            out = _call(fn, args, key, len(idxs), lanes, sharded,
+                        node_aware=aware)
             times[idxs] = np.reshape(out, -1)[:len(idxs)]
     if m.enabled and trace_count() == pre:
         m.histogram("fastsim.prepare_s").observe(prep.elapsed)
@@ -623,55 +794,74 @@ def sweep_hpl(configs: Configs, params: Params, *,
 
 
 def _plan(cfg_list: Sequence[HPLConfig],
-          prm_list: Sequence[FastSimParams]) -> List[tuple]:
+          prm_list: Sequence[FastSimParams],
+          nodes: Sequence[tuple]) -> List[tuple]:
     """The host's sweep assembly: the compiled calls ``(fn, args,
-    scenario indices, shape bucket, lanes, sharded)`` that answer every
-    scenario — one params-mode call per shared geometry, one batch-mode
-    call per shape bucket of the rest, single calls for loners."""
-    by_cfg: Dict[Tuple[int, int, int, int], List[int]] = {}
+    scenario indices, shape bucket, lanes, sharded, node-aware)`` that
+    answer every scenario — one params-mode call per shared geometry and
+    node block, one batch-mode call per shape bucket of the rest, single
+    calls for loners.  ``nodes`` holds each scenario's ``_node_args``."""
+    by_cfg: Dict[tuple, List[int]] = {}
     for idx, cfg in enumerate(cfg_list):
-        by_cfg.setdefault((cfg.N, cfg.nb, cfg.P, cfg.Q), []).append(idx)
+        by_cfg.setdefault((cfg.N, cfg.nb, cfg.P, cfg.Q, nodes[idx][:2]),
+                          []).append(idx)
 
     plan: List[tuple] = []
     mixed: Dict[Tuple[int, int, int], List[int]] = {}
-    for (N, nb, P, Q), idxs in by_cfg.items():
+    for (N, nb, P, Q, block), idxs in by_cfg.items():
         key = bucket_key(cfg_list[idxs[0]])
         if len(idxs) == 1:
             mixed.setdefault(key, []).append(idxs[0])
             continue
         lanes = _pad_lanes(idxs)
-        (stacked,), sharded = _shard_lanes(
-            len(lanes), _stack_params(prm_list, lanes))
-        plan.append((_compiled(*key, "params"),
-                     (np.int64(N), np.int64(nb), np.int64(P), np.int64(Q),
-                      stacked), idxs, key, len(lanes), sharded))
+        per_lane = [_stack_params(prm_list, lanes)]
+        if block:    # the intra-node latency is a lane's, as the params
+            per_lane.append(np.asarray([nodes[i][2] for i in lanes]))
+        (stacked, *alpha0), sharded = _shard_lanes(len(lanes), *per_lane)
+        args = (np.int64(N), np.int64(nb), np.int64(P), np.int64(Q),
+                stacked) + block + tuple(alpha0)
+        plan.append((_compiled(*key, "params", bool(block)), args,
+                     idxs, key, len(lanes), sharded, bool(block)))
     for key, idxs in mixed.items():
         if len(idxs) == 1:
-            plan.append((_compiled(*key, "single"),
-                         _single_args(cfg_list[idxs[0]], prm_list[idxs[0]]),
-                         idxs, key, 1, False))
+            i = idxs[0]
+            plan.append((_compiled(*key, "single", bool(nodes[i])),
+                         _single_args(cfg_list[i], prm_list[i], nodes[i]),
+                         idxs, key, 1, False, bool(nodes[i])))
         else:
-            plan.append(_batch_call(cfg_list, prm_list, idxs, key))
+            plan.append(_batch_call(cfg_list, prm_list, nodes, idxs, key))
     return plan
 
 
 def _batch_call(cfg_list: Sequence[HPLConfig],
-                prm_list: Sequence[FastSimParams], idxs: List[int],
-                key: Tuple[int, int, int]) -> tuple:
+                prm_list: Sequence[FastSimParams],
+                nodes: Sequence[tuple],
+                idxs: List[int], key: Tuple[int, int, int]) -> tuple:
     """One batch-mode call over scenarios ``idxs`` in shape bucket
-    ``key``: geometry and params both ride the padded lane axis."""
+    ``key``: geometry and params both ride the padded lane axis, and the
+    node arguments too where any lane has them (a node-blind lane rides
+    the node-aware program as the block (1, 1), which prices every hop
+    and swap round as the node-blind program does, bitwise)."""
     lanes = _pad_lanes(idxs)
     geom = np.asarray([[cfg_list[i].N, cfg_list[i].nb,
                         cfg_list[i].P, cfg_list[i].Q]
                        for i in lanes], np.int64)
-    args, sharded = _shard_lanes(
-        len(lanes), geom[:, 0], geom[:, 1], geom[:, 2], geom[:, 3],
-        _stack_params(prm_list, lanes))
-    return (_compiled(*key, "batch"), args, idxs, key, len(lanes), sharded)
+    cols = [geom[:, 0], geom[:, 1], geom[:, 2], geom[:, 3],
+            _stack_params(prm_list, lanes)]
+    aware = any(nodes[i] for i in idxs)
+    if aware:
+        lane_nodes = [nodes[i] or (1, 1, 0.0) for i in lanes]
+        cols += [np.asarray([n[0] for n in lane_nodes], np.int64),
+                 np.asarray([n[1] for n in lane_nodes], np.int64),
+                 np.asarray([n[2] for n in lane_nodes], np.float64)]
+    args, sharded = _shard_lanes(len(lanes), *cols)
+    return (_compiled(*key, "batch", aware), args, idxs, key, len(lanes),
+            sharded, aware)
 
 
 def _plan_forced(cfg_list: Sequence[HPLConfig],
                  prm_list: Sequence[FastSimParams],
+                 nodes: Sequence[tuple],
                  bucket: Tuple[int, int, int]) -> List[tuple]:
     """One batch-mode call for the whole sweep under a shared
     (rounded-up) bucket — exactly one traced program per distinct
@@ -684,5 +874,6 @@ def _plan_forced(cfg_list: Sequence[HPLConfig],
                 f"sweep_hpl: config (N={cfg.N}, nb={cfg.nb}, P={cfg.P}, "
                 f"Q={cfg.Q}) exceeds forced bucket "
                 f"({n_panels_max}, {P_max}, {Q_max})")
-    return [_batch_call(cfg_list, prm_list, list(range(len(cfg_list))),
+    return [_batch_call(cfg_list, prm_list, nodes,
+                        list(range(len(cfg_list))),
                         (n_panels_max, P_max, Q_max))]

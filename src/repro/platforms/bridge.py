@@ -62,7 +62,8 @@ def des_probe_runs(platform: Platform,
     if probe_configs is None:
         cap = platform.scale.n_ranks
         probe_configs = [HPLConfig(N=n, nb=nb, P=p, Q=q, lookahead=0,
-                                   bcast=platform.mpi.bcast)
+                                   bcast=platform.mpi.bcast,
+                                   pmap=platform.mpi.pmap)
                          for n, nb, p, q in DEFAULT_PROBES if p * q <= cap]
     if not probe_configs:
         raise ValueError(f"platform {platform.name!r}: no probe config "

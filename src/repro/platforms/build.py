@@ -119,7 +119,9 @@ def build_fastsim(platform: Platform, *, calibrated: bool = True):
     prm = FastSimParams.from_node(
         build_node(platform.node), link_bw=platform.fabric.link_bw,
         ranks_per_node=platform.scale.ranks_per_node,
-        net_latency=net_latency, hop_latency=platform.fabric.hop_latency)
+        net_latency=net_latency, hop_latency=platform.fabric.hop_latency,
+        # what the DES charges a message between two ranks of one node
+        intra_latency=platform.mpi.overhead + platform.fabric.base_latency)
     if calibrated and platform.calibration:
         prm = dataclasses.replace(prm, **platform.calibration_dict)
     return prm

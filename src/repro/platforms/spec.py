@@ -12,7 +12,8 @@ multiple simulation backends.  This module is that description: a
   * ``FabricSpec`` — the interconnect (fat-tree / dragonfly / torus /
     multipod geometry, link bandwidths, hop latencies),
   * ``MPIStackSpec`` — the software stack (per-call overhead, effective
-    small-message latency, default HPL broadcast algorithm),
+    small-message latency, default HPL broadcast algorithm and process
+    mapping),
   * ``ScaleSpec``  — deployment scale (node count, ranks per node, the
     machine's published HPL run geometry and TOP500 numbers),
 
@@ -105,6 +106,15 @@ class MPIStackSpec:
     net_latency: Optional[float] = None  # end-to-end small-msg latency;
     #                                  None -> derived from the fabric
     bcast: str = "1ring"             # default HPL panel-broadcast variant
+    # HPL.dat's process mapping of the published run: "col" (rank q*P + p
+    # at grid position (p, q)) or "row" (rank p*Q + q, HPL.dat's PMAP=0);
+    # with several ranks per node it decides which ranks share a NIC
+    pmap: str = "col"
+
+    def __post_init__(self):
+        if self.pmap not in ("col", "row"):
+            raise ValueError(f"MPIStackSpec: pmap={self.pmap!r} not in "
+                             "('col', 'row')")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -206,6 +216,7 @@ class Platform:
             raise ValueError(f"platform {self.name!r} has no default N; "
                              "pass N explicitly")
         kw.setdefault("bcast", self.mpi.bcast)
+        kw.setdefault("pmap", self.mpi.pmap)
         return HPLConfig(N=N, nb=nb if nb is not None else self.scale.hpl_nb,
                          P=P, Q=Q, **kw)
 
@@ -255,6 +266,10 @@ class Platform:
         d["contention"] = [[r, [list(kv) for kv in over]]
                            for r, over in self.contention]
         d["provenance"] = [list(kv) for kv in self.provenance]
+        if self.mpi.pmap == "col":
+            # the default mapping is left out, so that a record written
+            # before the field existed is the same record (and digest)
+            del d["mpi"]["pmap"]
         return d
 
     def to_json(self, **kw) -> str:

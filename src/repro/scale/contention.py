@@ -53,7 +53,7 @@ def scaled_probe_configs(platform, at_ranks: int, *,
     region = as_region(region)
     P, Q = square_grid(at_ranks)
     return [HPLConfig(N=nb * panels, nb=nb, P=P, Q=Q, lookahead=0,
-                      bcast=platform.mpi.bcast)
+                      bcast=platform.mpi.bcast, pmap=platform.mpi.pmap)
             for panels in (3 * region.panels, 4 * region.panels)]
 
 

@@ -84,7 +84,8 @@ def tune_scenario(platform: Platform, tuning: FleetTuning):
         N = memory_sized_n(proxy_nodes, platform.node.hbm_bytes, nb,
                            tuning.mem_fraction)
     return HPLConfig(N=N, nb=nb, P=P, Q=Q,
-                     bcast=platform.mpi.bcast), scale
+                     bcast=platform.mpi.bcast,
+                     pmap=platform.mpi.pmap), scale
 
 
 def fleet_bucket(cfgs: Sequence[object]) -> Tuple[int, int, int]:

@@ -301,3 +301,172 @@ def test_calibration_recovers_true_params():
     assert fit.loss < fit.loss0 / 100
     assert fit.params.gemm_eff == pytest.approx(true.gemm_eff, rel=0.05)
     assert fit.params.link_bw == pytest.approx(true.link_bw, rel=0.10)
+
+
+# --------------------------------------------- one rank per node: pinned
+
+# node-blind answers as the recurrence gave them before it learned about
+# nodes (float.hex), in each mode
+GOLDEN_R1 = {
+    "single": ["0x1.59308c3c9e1d0p-5"],
+    "params": ["0x1.f2fa12f18d0b1p-5", "0x1.db1547e080effp-5",
+               "0x1.dd46a5966975ap-5"],
+    "batch": ["0x1.f2fa12f18d0b1p-5", "0x1.81fba8a6c1e0ep-5",
+              "0x1.f61fc801fd9c4p-7"],
+}
+
+
+def _golden_params():
+    return [dataclasses.replace(BASE, link_bw=BASE.link_bw * s,
+                                lookahead=float(i % 2))
+            for i, s in enumerate((0.7, 1.0, 1.9))]
+
+
+@pytest.mark.parametrize("mode", ["single", "params", "batch"])
+@pytest.mark.parametrize("pmap", ["col", "row"])
+def test_one_rank_per_node_answers_are_pinned(mode, pmap):
+    """R = 1 under either mapping is the node-blind recurrence, bitwise."""
+    prms = _golden_params()
+    if mode == "single":
+        got = [simulate_hpl_fast(HPLConfig(N=3000, nb=128, P=3, Q=4,
+                                           pmap=pmap), BASE)["time_s"]]
+    elif mode == "params":
+        got = [r["time_s"] for r in sweep_hpl(
+            HPLConfig(N=4096, nb=128, P=4, Q=6, pmap=pmap), prms)]
+    else:
+        cfgs = [HPLConfig(N=4096, nb=128, P=4, Q=6, pmap=pmap),
+                HPLConfig(N=3500, nb=96, P=3, Q=5, pmap=pmap),
+                HPLConfig(N=2048, nb=64, P=2, Q=8, pmap=pmap)]
+        got = [r["time_s"] for r in sweep_hpl(cfgs, prms, bucket=(64, 4, 8))]
+    assert [t.hex() for t in got] == GOLDEN_R1[mode]
+
+
+def _jaxpr_text(mode):
+    """The node-blind program of ``mode`` at bucket 1536 x 6 x 8, printed
+    without source information, one run of spaces before each name-stack
+    comment."""
+    import re
+    lanes = () if mode == "single" else (4,)
+    geom = np.full(lanes if mode == "batch" else (), 4, np.int64)
+    prm = FastSimParams(**{n: np.ones(lanes) for n in fastsim._PARAM_FIELDS})
+    with jax.enable_x64(True):
+        closed = jax.make_jaxpr(fastsim._compiled(1536, 6, 8, mode))(
+            geom, geom, geom, geom, prm)
+    text = closed.jaxpr.pretty_print(source_info=False, name_stack=True)
+    return "".join(re.sub(r" {2,}#", " #", line).rstrip() + "\n"
+                   for line in (f"# mode {mode}, bucket 1536x6x8\n"
+                                + text + "\n").splitlines())
+
+
+def test_node_blind_program_is_unchanged():
+    """The one-rank-per-node programs are equation for equation those
+    recorded before the node-aware recurrence existed
+    (tests/data/fastsim_node_blind_jaxpr.txt)."""
+    from pathlib import Path
+    want = (Path(__file__).parent / "data"
+            / "fastsim_node_blind_jaxpr.txt").read_text()
+    got = "".join(_jaxpr_text(m) for m in ("single", "params", "batch"))
+    assert got == want
+
+
+def _on_nodes(prm, R, intra_latency=1.5e-6):
+    """``prm`` with ``R`` ranks a node and an intra-node latency."""
+    return dataclasses.replace(prm, ranks_per_node=R,
+                               intra_latency=intra_latency)
+
+
+def test_node_blind_lane_in_node_aware_program_is_bitwise():
+    """A batch that mixes machines of one and of several ranks per node
+    runs one node-aware program; its one-rank lanes answer bitwise what
+    the node-blind program answers."""
+    cfgs = [HPLConfig(N=3000, nb=128, P=4, Q=4),
+            HPLConfig(N=2900, nb=128, P=4, Q=6, pmap="row"),
+            HPLConfig(N=2800, nb=96, P=3, Q=5)]
+    prms = [_on_nodes(BASE, 2), _on_nodes(BASE, 3), BASE]
+    mixed = sweep_hpl(cfgs, prms, bucket=(32, 4, 6))
+    alone = sweep_hpl(cfgs[2:], prms[2:], bucket=(32, 4, 6))
+    assert mixed[2]["time_s"] == alone[0]["time_s"]
+    blind = sweep_hpl(cfgs[:2], [BASE, BASE], bucket=(32, 4, 6))
+    assert all(m["time_s"] != b["time_s"] for m, b in zip(mixed, blind))
+
+
+@pytest.mark.parametrize("P,Q,R,pmap,block", [
+    (8, 4, 4, "col", (4, 1)), (4, 8, 4, "col", (4, 1)),
+    (2, 8, 4, "col", (2, 2)), (4, 8, 4, "row", (1, 4)),
+    (8, 4, 4, "row", (1, 4)), (8, 2, 4, "row", (2, 2)),
+    (5, 5, 1, "row", None)])
+def test_node_block(P, Q, R, pmap, block):
+    cfg = HPLConfig(N=1024, nb=128, P=P, Q=Q, pmap=pmap)
+    assert fastsim.node_block(cfg, R) == block
+
+
+def test_node_aware_modes_agree_and_do_not_retrace():
+    """single, params and batch give one answer for a node-aware run;
+    another R or mapping on the same grid reuses the compiled program,
+    and never shares a params call with a different node block."""
+    cfg = HPLConfig(N=2900, nb=128, P=4, Q=6, pmap="row")
+    prms = [_on_nodes(p, 3) for p in _golden_params()]
+    params = [r["time_s"] for r in sweep_hpl(cfg, prms)]
+    single = [simulate_hpl_fast(cfg, p)["time_s"] for p in prms]
+    batch = [r["time_s"] for r in sweep_hpl([cfg] * 3, prms,
+                                            bucket=(32, 4, 6))]
+    np.testing.assert_allclose(params, single, rtol=1e-13)
+    np.testing.assert_allclose(batch, single, rtol=1e-13)
+    n0 = fastsim.trace_count()
+    sweep_hpl(dataclasses.replace(cfg, pmap="col"),
+              [_on_nodes(p, 2, 2e-6) for p in prms])
+    assert fastsim.trace_count() == n0
+    plan = fastsim._plan([cfg] * 4, [prms[0]] * 2 + [BASE] * 2,
+                         [(1, 3, 1.5e-6)] * 2 + [()] * 2)
+    assert [(p[2], p[6]) for p in plan] == [([0, 1], True), ([2, 3], False)]
+
+
+@pytest.mark.parametrize("mode", ["single", "params", "batch"])
+def test_node_aware_serial_step_does_no_integer_division(mode):
+    lanes = () if mode == "single" else (4,)
+    geom = np.full(lanes if mode == "batch" else (), 4, np.int64)
+    prm = FastSimParams(**{n: np.ones(lanes) for n in fastsim._PARAM_FIELDS})
+    with jax.enable_x64(True):
+        jaxpr = jax.make_jaxpr(fastsim._compiled(1536, 6, 8, mode, True))(
+            geom, geom, geom, geom, prm, geom, geom,
+            np.ones(lanes)).jaxpr
+    outer, inner = [e for e in _eqns(jaxpr) if e.primitive.name == "scan"]
+    assert _int_divisions(outer.params["jaxpr"].jaxpr)
+    assert not _int_divisions(inner.params["jaxpr"].jaxpr)
+
+
+def test_node_aware_lanes_are_counted():
+    from repro.obs import MetricsRegistry, global_metrics
+    cfg = HPLConfig(N=2900, nb=128, P=4, Q=6, pmap="row")
+    reg = MetricsRegistry()
+    with global_metrics(reg):
+        sweep_hpl([cfg] * 3, [_on_nodes(BASE, 3)] * 3)
+        sweep_hpl([cfg] * 2, [BASE] * 2)
+    c = reg.snapshot()["counters"]
+    assert c["fastsim.lanes_node_aware"] == 3
+    assert c["fastsim.lanes_live"] == 5
+
+
+def test_intra_node_latency_is_a_lane_parameter():
+    """Each lane prices its hops inside a node at its own latency, in one
+    params call: a lane's answer does not depend on its neighbours', and
+    a longer intra-node latency makes a run no faster."""
+    cfg = HPLConfig(N=2900, nb=128, P=4, Q=6, pmap="row")
+    lats = [1e-7, 1.5e-6, 5e-5]
+    prms = [_on_nodes(BASE, 3, a) for a in lats]
+    plan = fastsim._plan([cfg] * 3, prms,
+                         [fastsim._node_args(cfg, p) for p in prms])
+    assert len(plan) == 1
+    together = [r["time_s"] for r in sweep_hpl(cfg, prms)]
+    alone = [simulate_hpl_fast(cfg, p)["time_s"] for p in prms]
+    np.testing.assert_allclose(together, alone, rtol=1e-13)
+    assert together[0] < together[1] < together[2]
+
+
+def test_several_ranks_a_node_need_the_intra_node_latency():
+    cfg = HPLConfig(N=2900, nb=128, P=4, Q=6, pmap="row")
+    with pytest.raises(ValueError, match="intra_latency"):
+        simulate_hpl_fast(cfg, dataclasses.replace(BASE, ranks_per_node=3))
+    with pytest.raises(ValueError, match="intra_latency"):
+        sweep_hpl([cfg] * 2, [dataclasses.replace(BASE, ranks_per_node=3)]
+                  * 2)

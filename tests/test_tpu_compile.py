@@ -122,3 +122,17 @@ def test_fastsim_params_core_compiles_in_blocks(spec):
     assert fastsim._block_size(512) == 256
     c = _fastsim_params_core(spec, 512)
     assert c.memory_analysis() is not None
+
+
+def test_fastsim_node_aware_params_core_compiles(spec):
+    # the node-aware program: the node block and the lanes' intra-node
+    # latency after the params, the node tables (hpl.nodes) in the
+    # block's table pass
+    from repro.core import fastsim
+    with jax.enable_x64(True):
+        prm = fastsim.FastSimParams(**{n: spec((8,), jnp.float64)
+                                       for n in fastsim._PARAM_FIELDS})
+        geom = [spec((), jnp.int64)] * 6
+        c = fastsim._compiled(512, 4, 6, "params", True).lower(
+            *geom[:4], prm, *geom[4:], spec((8,), jnp.float64)).compile()
+    assert c.memory_analysis() is not None
