@@ -13,7 +13,9 @@ per-panel timing recurrence is a max-plus system over the P x Q grid:
                    inside the node latency alone (see ``_sim_core``)
   T_{k+1}(p,q)     = max(T_k, arrival, colmax(arrival)) + swap + update
 
-Everything is vectorized over the grid.  The panel loop runs in blocks
+Everything is vectorized over the grid, whose process rows are held as
+at most 9 row classes (NUMROC's case of a row in two panels running, see
+``_sim_core``).  The panel loop runs in blocks
 of up to 256 panels: a block first builds, as tables in one vectorised
 pass, everything its panels need that does not depend on the timing
 state (widths, NUMROC counts, broadcast hops, trsm, swap and
@@ -179,9 +181,91 @@ def bucket_key(cfg: HPLConfig) -> Tuple[int, int, int]:
 
 
 # ------------------------------------------------------------ traced core
+# NUMROC deals a process row of a panel one of three cases: a whole extra
+# block, the tail, or none.  The recurrence's state holds a slot per pair
+# (case in panel k, case in panel k+1), slot 3 * first + second, in
+# place of a row per process (``_sim_core``)
+_CASES = 3
+_SLOTS = _CASES * _CASES
 # most panels per block of _sim_core (128, 256 and 512 ran Frontera's
 # recurrence within 3% of each other on a TPU v5e)
 _BLOCK = 256
+
+
+@functools.partial(jax.custom_jvp, nondiff_argnums=(3,))
+def _row_max(x, where, n_rows, axes):
+    """Max of ``x`` along ``axes`` over the entries ``where`` marks, each
+    slot standing for ``n_rows`` process rows (both broadcast against
+    ``x``).  The derivative shares a tie among the tied rows, as the max
+    over a row per process does: a slot's share is its rows'."""
+    return jnp.max(x, axis=axes, where=where, initial=-jnp.inf)
+
+
+@_row_max.defjvp
+def _row_max_jvp(axes, primals, tangents):
+    x, where, n_rows = primals
+    ans = _row_max(x, where, n_rows, axes)
+    hit = jnp.where(where & (x == jnp.expand_dims(ans, axes)), n_rows, 0.0)
+    return ans, (jnp.sum(hit * tangents[0], axis=axes)
+                 / jnp.sum(hit, axis=axes))
+
+
+def _numroc(blocks, tail, nb, shift, nprocs, size):
+    """Vectorized NUMROC for procs 0..size-1 of panels with ``blocks``
+    whole ``nb``-blocks and ``tail`` columns left (K,), dealt from owner
+    ``shift`` ((K,) or scalar, in [0, nprocs)): the local count of each
+    case — a whole extra block, the tail, none — (K, 3), and the case of
+    each proc (K, size)."""
+    ip = jnp.arange(size) - jnp.reshape(shift, (-1, 1))
+    ip = jnp.where(ip < 0, ip + nprocs, ip)  # (p - shift) % nprocs, p live
+    per = blocks // nprocs
+    extra = (blocks - per * nprocs)[:, None]
+    counts = per[:, None] * nb + jnp.stack(
+        [jnp.broadcast_to(nb, tail.shape), tail, jnp.zeros_like(tail)],
+        axis=1)
+    return counts, jnp.where(ip < extra, 0, jnp.where(ip == extra, 1, 2))
+
+
+def _by_case(counts, case):
+    """Each proc's entry of ``counts`` (K, 3) by its ``case`` (K, size),
+    by selects: a gather, per lane in batch mode, is slow on the TPU."""
+    return jnp.where(case == 0, counts[:, :1],
+                     jnp.where(case == 1, counts[:, 1:2], counts[:, 2:]))
+
+
+def _to_slots(x, second: bool):
+    """Per case (K, 3) to per slot (K, S): slot 3 * a + b takes the
+    entry of its pair's first case a, or of its ``second`` b."""
+    y = x[:, None, :] if second else x[:, :, None]
+    return jnp.broadcast_to(y, (x.shape[0], _CASES, _CASES)).reshape(
+        x.shape[0], _SLOTS)
+
+
+def _geometry(n_blocks, n_tail, nb, P, P_max, ks):
+    """Per panel of ``ks`` (K,) of a matrix of ``n_blocks`` whole
+    ``nb``-blocks and ``n_tail`` trailing columns on ``P`` process rows:
+    whole blocks and tail left (K,), width (K,), each row case's local
+    rows (K, 3) and the case of each process row 0..P_max-1 (K, P_max).
+    Panel k has max(n_blocks - k, 0) whole blocks and the tail (while
+    k <= n_blocks) left, so the tables divide only by P."""
+    blocks = jnp.maximum(n_blocks - ks, 0)
+    tail = jnp.where(ks <= n_blocks, n_tail, 0)
+    # nb except on the trailing partial panel, 0 past the live panels
+    width = jnp.where(blocks > 0, nb, tail)
+    owner = ks - ks // P * P
+    rows, case = _numroc(blocks, tail, nb, owner, P, P_max)
+    return (blocks, tail, width.astype(jnp.float64),
+            rows.astype(jnp.float64), case)
+
+
+def _slot_rows(case, P):
+    """How many live process rows (p < ``P``) each slot of the state
+    between panels k and k+1 stands for, given each process row's case
+    in panels k .. k+K (K+1, P_max): (K, S)."""
+    pair = _CASES * case[:-1] + case[1:]                     # (K, P_max)
+    row_on = jnp.arange(case.shape[1]) < P
+    return jnp.sum((pair[:, :, None] == jnp.arange(_SLOTS))
+                   & row_on[None, :, None], axis=1).astype(jnp.float64)
 
 
 def _block_size(n_panels_max: int) -> int:
@@ -198,27 +282,47 @@ def _sim_core(N, nb, P, Q, prm: FastSimParams,
     """HPL panel recurrence with *traced* (N, nb, P, Q, prm).
 
     Shapes are the static bucket (P_max, Q_max) and the loop runs
-    n_panels_max panels; rows p >= P, columns q >= Q and panels
-    k >= ceil(N/nb) are padding, masked so they never touch live lanes (the
-    ring-broadcast permutation maps padding columns to themselves, the
-    column-sync max and the final max are mask-reduced, and the loop
-    carry freezes once k reaches the live panel count).
+    n_panels_max panels; rows p >= P, columns q >= Q and panels k >=
+    ceil(N/nb) are padding, masked so they never touch live lanes (padding
+    rows fill no row class, the ring-broadcast permutation maps padding
+    columns to themselves, the column-sync max and the final max are
+    mask-reduced, and the loop carry freezes once k reaches the live
+    panel count).
 
     The panels run in blocks of C = ``_block_size(n_panels_max)``.  Each
     block first builds, in one vectorised pass (named scope
     ``hpl.tables``), everything its C panels need that does not read the
-    carry: widths, NUMROC row and column counts, broadcast hops, trsm,
-    swap and next-panel factorization times, the lookahead gemm and the
-    live flag, each a table with a leading axis of C.  A serial loop of C
-    steps then runs only the chain that reads the carry ``(T,
-    fact_done)``: ring arrival (prefix max), the update's gemm from the
-    tables' row and column counts, column sync, the next panel's
-    factorization anchor, the freeze and the ring re-base.  Every value
-    is computed by the same float64 operations in the same order as a
-    single per-panel step would.
+    carry: widths, NUMROC row and column counts, the rows each row class
+    stands for, broadcast hops, trsm, swap and next-panel factorization
+    times, the lookahead gemm and the live flag, each a table with a
+    leading axis of C.  A serial loop of C steps then runs only the
+    chain that reads the carry ``(T, fact_done)``: ring arrival (prefix
+    max), the update's gemm from the tables' row and column counts,
+    column sync, the next panel's factorization anchor, the freeze and
+    the ring re-base.  Every value is computed by the same float64
+    operations in the same order as a single per-panel step would.
+
+    The state holds a slot per row class, not a row per process.  Only
+    the column max mixes process rows; every other value is row-local
+    and reads its row p only through p's local rows, which NUMROC gives
+    by one of three cases a panel (a whole extra block, the tail, none):
+    the update's gemm reads panel k's, the next panel's factorization
+    anchor panel k+1's, and the next step's broadcast hop panel k+1's.
+    So after step k a row's ``(T, fact_done)`` is a function of the pair
+    (case in panel k, case in panel k+1), and every row of one pair holds
+    the same values: the state is (S, Q_max, B), S = 9 slots, slot
+    3 * a + b for the pair (a, b).  A step starts from the slots of the
+    pair (k-1, k), writes those of (k, k+1), and takes the column max
+    over the slots some live row p < P fills (``n_rows`` > 0, a table of
+    the pass); max is exact and idempotent, so the answer is the per-row
+    program's, bitwise.  Panel 0 starts from the pair (-1, 0): zeros and
+    the factorization of panel 0's case.  Frozen panels keep the slots of
+    the last live panel, and the final max reads those.  The derivative
+    of a max shares a tie among the tied process rows (``_row_max``), so
+    gradients are the per-row program's too, to rounding.
 
     ``prm`` leaves are (B,)-vectors: the whole recurrence carries a
-    *trailing* scenario-batch axis — grid state is (P_max, Q_max, B) —
+    *trailing* scenario-batch axis — grid state is (S, Q_max, B) —
     so a hardware what-if grid runs as one program whose gathers and
     ring permutations move contiguous B-sized blocks (a leading vmap
     axis would make every gather element-strided; measured ~4x slower).
@@ -279,9 +383,7 @@ def _sim_core(N, nb, P, Q, prm: FastSimParams,
         # a node of several rows holds pairs that stay on it, at alpha0
         swap_floor = jnp.where(node_rows > 1, alpha0, 0.0)       # (B,)
 
-    row_on = jnp.arange(P_max) < P
     col_on = jnp.arange(Q_max) < Q
-    active = row_on[:, None] & col_on[None, :]
     iq = jnp.arange(Q_max)
 
     # whole nb-blocks and trailing columns of the matrix: panel k has
@@ -292,35 +394,13 @@ def _sim_core(N, nb, P, Q, prm: FastSimParams,
     # ceil: a trailing N % nb panel is simulated at its true width
     n_panels = n_blocks + (n_tail > 0)
 
-    def numroc_vec(blocks, tail, shift, nprocs, size):
-        """Vectorized NUMROC for procs 0..size-1 of panels with ``blocks``
-        whole blocks and ``tail`` columns left (K,), dealt from owner
-        ``shift`` ((K,) or scalar, in [0, nprocs)): (K, size)."""
-        ip = jnp.arange(size) - jnp.reshape(shift, (-1, 1))
-        ip = jnp.where(ip < 0, ip + nprocs, ip)  # (p - shift) % nprocs, p live
-        blocks = blocks[:, None]
-        per = blocks // nprocs
-        extra = blocks - per * nprocs
-        return (per * nb + jnp.where(ip < extra, nb,
-                                     jnp.where(ip == extra, tail[:, None],
-                                               0))
-                ).astype(f64)
-
     def geometry(ks):
-        """Per panel of ``ks`` (K,): whole blocks and tail left, width
-        (K,) and local rows (K, P)."""
-        blocks = jnp.maximum(n_blocks - ks, 0)
-        tail = jnp.where(ks <= n_blocks, n_tail, 0)
-        # nb except on the trailing partial panel, 0 past the live panels
-        width = jnp.where(blocks > 0, nb, tail)
-        owner = ks - ks // P * P
-        return (blocks, tail, width.astype(f64),
-                numroc_vec(blocks, tail, owner, P, P_max))
+        return _geometry(n_blocks, n_tail, nb, P, P_max, ks)
 
     def fact_time(wf, mloc):
         """Factorization cost per row rank of panels of width ``wf`` (K,)
-        over ``mloc`` (K, P) local rows (SimBLAS closed forms):
-        dger/dscal/idamax are Level-1/2 memory-bound.  Returns (K, P, B)."""
+        over ``mloc`` (K, S) local rows (SimBLAS closed forms):
+        dger/dscal/idamax are Level-1/2 memory-bound.  Returns (K, S, B)."""
         with jax.named_scope("hpl.fact"):
             wf = wf[:, None]
             pf_bytes = 8.0 * (jnp.maximum(mloc * wf * wf - wf ** 3 / 3.0,
@@ -334,26 +414,33 @@ def _sim_core(N, nb, P, Q, prm: FastSimParams,
     def tables(k0):
         """What panels k0 .. k0+C-1 need that does not read the carry,
         each with a leading axis of C."""
-        # one geometry pass over the block's panels and the next one:
-        # panel k+1's widths and rows feed panel k's lookahead
-        ks = k0 + jnp.arange(C + 1)
-        blocks, tail, w_all, mloc_all = geometry(ks)
-        wf, mloc = w_all[:C], mloc_all[:C]                   # (C,), (C, P)
-        w_next, mloc_n = w_all[1:], mloc_all[1:]
+        # one geometry pass over the block's panels, the one before (the
+        # state a panel starts from holds its row classes) and the one
+        # after (panel k+1's widths and rows feed panel k's lookahead)
+        ks = k0 - 1 + jnp.arange(C + 2)
+        blocks, tail, w_all, rows, case = geometry(ks)
+        wf, w_next = w_all[1:C + 1], w_all[2:]                   # (C,)
+        rows_k, rows_n = rows[1:C + 1], rows[2:]                 # (C, 3)
         # the update's columns: those left after the panel, ord space
-        nloc = numroc_vec(blocks[1:], tail[1:], 1, Q, Q_max)     # (C, Q)
+        nloc = _by_case(*_numroc(blocks[2:], tail[2:], nb, 1, Q, Q_max)
+                        ).astype(f64)                            # (C, Q)
         w = wf[:, None]
-        panel_bytes = 8.0 * (mloc + w) * w                       # (C, P)
+        # the slot a step starts from has panel k's case second; the
+        # slot it writes has it first, and panel k+1's second
+        panel_bytes = 8.0 * (_to_slots(rows_k, True) + w) * w    # (C, S)
         if nodes:
             # the block's rows share the NIC on a node-leaving hop
             panel_bytes = panel_bytes * node_rows.astype(f64)
-        tb = {"wf": wf, "mloc": mloc, "nloc": nloc,
-              "hop": alpha + panel_bytes[:, :, None] / bcast_bw,  # (C, P, B)
+        mloc_n = _to_slots(rows_n, True)                         # (C, S)
+        n_rows = _slot_rows(case[:C + 1], P)                     # (C, S)
+        tb = {"wf": wf, "mloc": _to_slots(rows_k, False), "nloc": nloc,
+              "n_rows": n_rows, "present": n_rows > 0,
+              "hop": alpha + panel_bytes[:, :, None] / bcast_bw,  # (C, S, B)
               "trsm": (w * w * nloc)[:, :, None] / peak + theta,  # (C, Q, B)
-              "ft": fact_time(w_next, mloc_n),                   # (C, P, B)
+              "ft": fact_time(w_next, mloc_n),                   # (C, S, B)
               "gemm_nb": (2.0 * mloc_n[:, :, None] * w_next[:, None, None]
-                          * wf[:, None, None]) / peak + theta,   # (C, P, B)
-              "live": ks[:C] < n_panels}
+                          * wf[:, None, None]) / peak + theta,   # (C, S, B)
+              "live": ks[1:C + 1] < n_panels}
         if P_max > 1:
             u_bytes = 8.0 * w * nloc                             # (C, Q)
             if not nodes:
@@ -376,7 +463,7 @@ def _sim_core(N, nb, P, Q, prm: FastSimParams,
                     0.0)                                         # (C, Q, B)
         if nodes:
             with jax.named_scope("hpl.nodes"):
-                tb.update(ring_hops(ks[:C]))
+                tb.update(ring_hops(ks[1:C + 1]))
         return tb
 
     def ring_hops(ks):
@@ -465,26 +552,21 @@ def _sim_core(N, nb, P, Q, prm: FastSimParams,
         with jax.named_scope("hpl.update"):
             gemm = (2.0 * mloc[:, None, None] * nloc[None, :, None] * wf
                     + 2.0 * mloc[:, None, None] * nloc[None, :, None]) \
-                / peak + theta                           # (P, Q, B)
-        # 3. row swaps: column ranks exchange the U strip (sync on colmax)
-        if P_max > 1:                    # P > 1 exactly (bucket(1) == 1)
-            with jax.named_scope("hpl.swap"):
-                # column sync: every rank of a column proceeds from the
-                # column max, so after_swap is row-independent — a (Q, B)
-                # row vector instead of a (P, Q, B) grid.
-                colmax = jnp.max(jnp.maximum(arrival, T), axis=0,
-                                 where=row_on[:, None, None],
-                                 initial=-jnp.inf)       # (Q, B)
-                after_swap = colmax + tb["swap"]         # (Q, B)
-            with jax.named_scope("hpl.update"):
-                T_new = (after_swap + tb["trsm"])[None, :, :] + gemm
-            as_next = after_swap[idx1]                   # (B,) static slice
-        else:
-            with jax.named_scope("hpl.swap"):
-                after_swap = jnp.maximum(arrival, T)     # (1, Q, B)
-            with jax.named_scope("hpl.update"):
-                T_new = after_swap + tb["trsm"][None, :, :] + gemm
-            as_next = after_swap[:, idx1, :]             # (P=1, B)
+                / peak + theta                           # (S, Q, B)
+        # 3. row swaps: column ranks exchange the U strip, and every rank
+        # of a column proceeds from the column max over the slots the
+        # live rows fill, so after_swap is row-independent — a (Q, B)
+        # row vector.  bucket(1) == 1, so P_max > 1 exactly when P > 1:
+        # only then is there a swap to price
+        with jax.named_scope("hpl.swap"):
+            after_swap = _row_max(jnp.maximum(arrival, T),
+                                  tb["present"][:, None, None],
+                                  tb["n_rows"][:, None, None], 0)  # (Q, B)
+            if P_max > 1:
+                after_swap = after_swap + tb["swap"]
+        with jax.named_scope("hpl.update"):
+            T_new = (after_swap + tb["trsm"])[None, :, :] + gemm
+        as_next = after_swap[idx1]                       # (B,) static slice
 
         # 1'. (lookahead) factor panel k+1 on its owning column, anchored
         # right after that column updates just the next panel's columns.
@@ -511,14 +593,16 @@ def _sim_core(N, nb, P, Q, prm: FastSimParams,
             tb = tables(j * C)
         return jax.lax.scan(chain, carry, tb)[0]
 
-    T0 = jnp.zeros((P_max, Q_max, B), f64)
+    T0 = jnp.zeros((_SLOTS, Q_max, B), f64)
     with jax.named_scope("hpl.tables"):
         # panel 0: nothing to overlap with
-        _, _, w0, mloc0 = geometry(jnp.zeros(1, jnp.int64))
-        F0 = fact_time(w0, mloc0)[0]
+        _, _, w0, rows0, _ = geometry(jnp.zeros(1, jnp.int64))
+        F0 = fact_time(w0, _to_slots(rows0, True))[0]
+        # the slots the last live panel wrote, frozen with the state
+        last = _slot_rows(geometry(n_panels - 1 + jnp.arange(2))[4], P)[0]
     T, _ = jax.lax.fori_loop(0, n_panels_max // C, block, (T0, F0))
-    total = jnp.max(jnp.where(active[:, :, None], T, -jnp.inf),
-                    axis=(0, 1))                         # (B,)
+    total = _row_max(T, (last > 0)[:, None, None] & col_on[None, :, None],
+                     last[:, None, None], (0, 1))                # (B,)
     # back substitution: ~2 N^2 flops + N broadcasts (minor)
     total = total + 2.0 * N * N / (peak * P * Q) + N / nb * alpha
     return total
@@ -644,7 +728,8 @@ def _compiled(n_panels_max: int, P_max: int, Q_max: int, mode: str,
 
 def _call(fn, args, key: Tuple, live: int, lanes: int,
           sharded: bool = False, prefix: str = "fastsim",
-          traces=trace_count, node_aware: bool = False) -> np.ndarray:
+          traces=trace_count, node_aware: bool = False,
+          rows: int = 0) -> np.ndarray:
     """Run one compiled program and bring its answer to the host.
 
     With the global metrics registry on, the call is two spans on the
@@ -655,7 +740,9 @@ def _call(fn, args, key: Tuple, live: int, lanes: int,
     (``traces`` is the model's trace counter), with its lane occupancy
     — padding lanes are pure waste — the live lanes that ran the
     node-aware program (``node_aware``), and, on hits only, the launch,
-    wait and whole-dispatch wall times (a miss's wall is the compile's)."""
+    wait and whole-dispatch wall times (a miss's wall is the compile's).
+    A panel recurrence gives ``rows``, its bucket's process rows P_max:
+    the state rows its lanes carry and the bucket's rows are counted."""
     m = get_global_metrics()
     if not m.enabled:
         return np.asarray(fn(*args))
@@ -679,6 +766,9 @@ def _call(fn, args, key: Tuple, live: int, lanes: int,
     m.counter(f"{prefix}.lanes_padded").inc(lanes - live)
     if node_aware:
         m.counter(f"{prefix}.lanes_node_aware").inc(live)
+    if rows:
+        m.counter(f"{prefix}.rows_carried").inc(lanes * _SLOTS)
+        m.counter(f"{prefix}.rows_bucket").inc(lanes * rows)
     _record_shard(m, sharded, prefix)
     return out
 
@@ -697,7 +787,7 @@ def _run_single(cfg: HPLConfig, prm: FastSimParams) -> float:
     nodes = _node_args(cfg, prm)
     return float(_call(_compiled(*key, "single", bool(nodes)),
                        _single_args(cfg, prm, nodes), key, 1, 1,
-                       node_aware=bool(nodes)))
+                       node_aware=bool(nodes), rows=key[1]))
 
 
 def _stack_params(prm_list: Sequence[FastSimParams],
@@ -786,7 +876,7 @@ def sweep_hpl(configs: Configs, params: Params, *,
                     else _plan_forced(cfg_list, prm_list, nodes, bucket))
         for fn, args, idxs, key, lanes, sharded, aware in plan:
             out = _call(fn, args, key, len(idxs), lanes, sharded,
-                        node_aware=aware)
+                        node_aware=aware, rows=key[1])
             times[idxs] = np.reshape(out, -1)[:len(idxs)]
     if m.enabled and trace_count() == pre:
         m.histogram("fastsim.prepare_s").observe(prep.elapsed)

@@ -8,7 +8,7 @@ import jax.extend.core as jex
 import numpy as np
 import pytest
 
-from repro.core.apps.hpl import HPLConfig
+from repro.core.apps.hpl import HPLConfig, numroc
 from repro.core import fastsim
 from repro.core.fastsim import (FastSimParams, bucket_key,
                                 simulate_hpl_fast, simulate_time_traced,
@@ -360,8 +360,8 @@ def _jaxpr_text(mode):
 
 def test_node_blind_program_is_unchanged():
     """The one-rank-per-node programs are equation for equation those
-    recorded before the node-aware recurrence existed
-    (tests/data/fastsim_node_blind_jaxpr.txt)."""
+    recorded when the state took row classes: node-aware pricing adds
+    nothing to them (tests/data/fastsim_node_blind_jaxpr.txt)."""
     from pathlib import Path
     want = (Path(__file__).parent / "data"
             / "fastsim_node_blind_jaxpr.txt").read_text()
@@ -470,3 +470,195 @@ def test_several_ranks_a_node_need_the_intra_node_latency():
     with pytest.raises(ValueError, match="intra_latency"):
         sweep_hpl([cfg] * 2, [dataclasses.replace(BASE, ranks_per_node=3)]
                   * 2)
+
+
+# ------------------------------------ row classes: the state's slots
+
+def _numroc_np(n, nb, iproc, nprocs):
+    """ScaLAPACK's NUMROC (``apps.hpl.numroc``) over NumPy arrays."""
+    nblocks = n // nb
+    extra = nblocks % nprocs
+    return (nblocks // nprocs) * nb + np.where(
+        iproc < extra, nb, np.where(iproc == extra, n % nb, 0))
+
+
+def _check_row_classes(N, nb, P, n_panels_max=None, P_max=None):
+    """The table pass's row classes against every process row's local
+    rows, in every panel of the bucket (or of ``n_panels_max``) from the
+    one before panel 0 to the padding panels past the live count, on
+    ``P_max`` process rows (the bucket's by default)."""
+    P_max = P_max or fastsim._bucket(P)
+    n_panels_max = n_panels_max or fastsim._bucket(-(-N // nb))
+    ks = np.arange(-1, n_panels_max + 1)
+    with jax.enable_x64(True):
+        _, _, _, rows, case = fastsim._geometry(
+            N // nb, N % nb, nb, P, P_max, jax.numpy.asarray(ks))
+        n_rows = np.asarray(fastsim._slot_rows(case, P))
+    rows, case = np.asarray(rows), np.asarray(case)
+    # panel k deals its N - k*nb trailing rows from process row k % P
+    p = np.arange(P)
+    mloc = _numroc_np(np.maximum(N - ks * nb, 0)[:, None], nb,
+                      (p[None, :] - ks[:, None]) % P, P)       # (K, P)
+    if len(ks) <= 80:
+        assert mloc.tolist() == [
+            [numroc(max(N - k * nb, 0), nb, (q - k) % P, P) for q in p]
+            for k in ks]
+    # each process row's case holds its local rows ...
+    np.testing.assert_array_equal(
+        np.take_along_axis(rows, case[:, :P], axis=1), mloc)
+    # ... and each slot counts the live rows of its pair of cases
+    assert n_rows.shape == (len(ks) - 1, fastsim._SLOTS)
+    assert (n_rows.sum(axis=1) == P).all()
+    for i in range(len(ks) - 1):
+        slots = {divmod(s, fastsim._CASES): n
+                 for s, n in enumerate(n_rows[i]) if n}
+        pairs = list(zip(case[i, :P].tolist(), case[i + 1, :P].tolist()))
+        assert slots == {pr: pairs.count(pr) for pr in set(pairs)}
+        assert ({(rows[i, a], rows[i + 1, b]) for a, b in slots}
+                == set(zip(mloc[i].tolist(), mloc[i + 1].tolist())))
+    return n_rows
+
+
+@pytest.mark.parametrize("N,nb,P", [
+    (9282848, 384, 88),        # Frontera's published run
+    (16473600, 384, 144),      # Summit's: N % nb == 0
+    (32785, 32, 5),            # params_blocks: a trailing 17-column panel
+    (4096, 128, 1),            # P = 1: one case, the tail, every panel
+    (100, 128, 3),             # one partial panel, fewer panels than rows
+    (12000, 32, 11),           # N % nb == 0, a 12-row bucket
+])
+def test_row_classes_match_numroc(N, nb, P):
+    n_rows = _check_row_classes(N, nb, P)
+    # at most four slots a panel are filled, of seven that ever are
+    assert (n_rows > 0).sum(axis=1).max() <= 4
+    assert n_rows[:, [1, 3]].sum() == 0
+
+
+def test_row_classes_match_numroc_random_geometries():
+    # one shape for all (64 panels, 48 process rows), so that the table
+    # pass compiles once: up to 48 live panels and 40 live rows
+    rng = np.random.default_rng(17)
+    for _ in range(200):
+        nb = int(rng.integers(1, 257))
+        _check_row_classes(int(rng.integers(1, 48 * nb + 1)), nb,
+                           int(rng.integers(1, 41)), 64, 48)
+
+
+# float.hex of answers in buckets of more than 9 process rows, as the
+# program with a state row per process row (before the state held row
+# classes) computed them on CPU
+GOLDEN_ROWS_CASES = {
+    # params mode, P = 11 in a 12-row bucket, N % nb == 0, two blocks
+    "params_p11": lambda: _golden_sweep(
+        [HPLConfig(N=12000, nb=32, P=11, Q=5)] * 3),
+    # batch mode in a 16-row bucket, a P = 1 lane among them
+    "batch_p16": lambda: _golden_sweep(
+        [HPLConfig(N=9001, nb=32, P=13, Q=3),
+         HPLConfig(N=7000, nb=48, P=11, Q=4),
+         HPLConfig(N=6400, nb=64, P=10, Q=2),
+         HPLConfig(N=5000, nb=32, P=16, Q=4),
+         HPLConfig(N=3000, nb=64, P=1, Q=4)], bucket=(384, 16, 4)),
+    "single_p13": lambda: [simulate_hpl_fast(
+        HPLConfig(N=9001, nb=32, P=13, Q=3), _params_for(2))["time_s"]],
+    # node-aware params: 3 ranks a node down the columns of P = 12
+    "nodes_params_p12": lambda: [r["time_s"] for r in sweep_hpl(
+        HPLConfig(N=10000, nb=32, P=12, Q=4),
+        [_on_nodes(_params_for(i), 3) for i in range(3)])],
+    # node-aware params, nodes of whole rows under the row mapping
+    "nodes_params_row": lambda: [r["time_s"] for r in sweep_hpl(
+        HPLConfig(N=8000, nb=32, P=11, Q=2, pmap="row"),
+        [_on_nodes(_params_for(i), 4) for i in range(2)])],
+    # node-aware batch with a node-blind lane, a 16-row bucket
+    "nodes_batch_p16": lambda: [r["time_s"] for r in sweep_hpl(
+        [HPLConfig(N=9000, nb=32, P=14, Q=3),
+         HPLConfig(N=7001, nb=48, P=12, Q=4, pmap="row"),
+         HPLConfig(N=6000, nb=64, P=11, Q=2)],
+        [_on_nodes(_params_for(0), 2), _on_nodes(_params_for(1), 2),
+         _params_for(2)], bucket=(384, 16, 4))],
+}
+
+GOLDEN_ROWS = {
+    "params_p11": ["0x1.085fdac8ae8a3p-2", "0x1.e176a98efdd34p-3",
+                   "0x1.0428d1a22b38ap-2"],
+    "batch_p16": ["0x1.806c81854b062p-3", "0x1.0f266bfc0afcep-3",
+                  "0x1.148d8230736a3p-3", "0x1.6e8942f08f591p-4",
+                  "0x1.4d1ca991a0472p-5"],
+    "single_p13": ["0x1.7a1c3ee5fa550p-3"],
+    "nodes_params_p12": ["0x1.b6ab72b826017p-3", "0x1.94fc69d3e5a81p-3",
+                         "0x1.ad6471aa5f864p-3"],
+    "nodes_params_row": ["0x1.779db70e5b2f1p-3", "0x1.5125e9c779145p-3"],
+    "nodes_batch_p16": ["0x1.819688fbd7346p-3", "0x1.13396d965bbc5p-3",
+                        "0x1.f87a64d51c5fbp-4"],
+}
+
+
+@pytest.mark.parametrize("case", sorted(GOLDEN_ROWS_CASES))
+def test_row_class_state_matches_per_row_answers_bitwise(case):
+    assert [t.hex() for t in GOLDEN_ROWS_CASES[case]()] == GOLDEN_ROWS[case]
+
+
+# gradients of the per-row program (as GOLDEN_ROWS): a max shares a tie
+# among the tied process rows, so a slot weighs as the rows it holds
+GOLDEN_ROWS_GRAD = {
+    "params_p11": (HPLConfig(N=12000, nb=32, P=11, Q=5), 1, {
+        "peak_flops": "-0x1.60f276a51bf3fp-52",
+        "gemm_eff": "-0x1.cc91feca2360cp-12",
+        "mem_bw": "-0x1.59bc3e201102ep-43",
+        "theta": "0x1.1c30000000000p+15",
+        "link_bw": "-0x1.28b4c4208efc4p-41",
+        "net_latency": "0x1.7fc9000000000p+16",
+        "hop_latency": "0x0.0p+0",
+        "bcast_bw_scale": "-0x1.9c60482d7d693p-9",
+        "swap_bw_scale": "-0x1.a3debd7b5ceabp-8",
+        "lookahead": "-0x1.45a3105528bfdp-6"}),
+    "nodes_p12": (HPLConfig(N=10000, nb=32, P=12, Q=4), 3, {
+        "peak_flops": "-0x1.c62880bb38bf7p-53",
+        "gemm_eff": "-0x1.28525adf54f00p-12",
+        "mem_bw": "-0x1.d94158794a0d1p-44",
+        "theta": "0x1.d9a8000000001p+14",
+        "link_bw": "-0x1.6b9705c6d501ap-41",
+        "net_latency": "0x1.3fd3800000000p+16",
+        "hop_latency": "0x0.0p+0",
+        "bcast_bw_scale": "-0x1.934c24502953cp-8",
+        "swap_bw_scale": "-0x1.6be32cc03f760p-8",
+        "lookahead": "-0x1.b525c04316483p-7"}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GOLDEN_ROWS_GRAD))
+def test_row_class_gradient_matches_per_row_gradient(case):
+    cfg, R, want = GOLDEN_ROWS_GRAD[case]
+    prm = _params_for(3)
+    if R > 1:
+        prm = _on_nodes(prm, R)
+    with jax.enable_x64(True):
+        g = jax.grad(lambda p: simulate_time_traced(cfg, p))(
+            fastsim._f64_params(prm))
+    for name, w in want.items():
+        w = float.fromhex(w)
+        assert abs(float(getattr(g, name)) - w) <= 1e-12 * abs(w), name
+
+
+def test_rows_carried_and_bucket_rows_are_counted():
+    """A params call of 4 lanes and a batch call of 2 in a 12-row bucket
+    count 9 state rows a lane carried and 12 bucket rows a lane; with the
+    registry off nothing is counted, and the answers are the same."""
+    from repro.obs import MetricsRegistry, global_metrics
+
+    def calls():
+        cfg = HPLConfig(N=3000, nb=64, P=11, Q=3)
+        return (sweep_hpl(cfg, [_params_for(i) for i in range(3)])
+                + sweep_hpl([cfg, HPLConfig(N=2000, nb=64, P=5, Q=3)],
+                            [BASE, BASE], bucket=(64, 12, 4)))
+    reg = MetricsRegistry()
+    with global_metrics(reg):
+        on = calls()
+    c = reg.snapshot()["counters"]
+    assert c["fastsim.rows_carried"] == (4 + 2) * fastsim._SLOTS
+    assert c["fastsim.rows_bucket"] == (4 + 2) * 12
+    assert c["fastsim.lanes_live"] + c["fastsim.lanes_padded"] == 4 + 2
+    with global_metrics(None):
+        off = calls()
+        assert fastsim.get_global_metrics().snapshot()["counters"] == {}
+    assert off == on
+    assert reg.snapshot()["counters"] == c
