@@ -262,11 +262,11 @@ def run(quick: bool = True):
     orig = HPLFastModel.sweep_models.__func__
     state = {"n": 0}
 
-    def flaky(cls, models):
+    def flaky(cls, models, *, shard=False):
         state["n"] += 1
         if state["n"] == 1:
             raise RuntimeError("transient backend hiccup")
-        return orig(cls, models)
+        return orig(cls, models, shard=shard)
 
     HPLFastModel.sweep_models = classmethod(flaky)
     try:

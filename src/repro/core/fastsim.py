@@ -612,50 +612,23 @@ def _sim_core(N, nb, P, Q, prm: FastSimParams,
 # Device-sharded batch dispatch (DESIGN.md §20): the sweep engine's
 # trailing/leading scenario axis is embarrassingly parallel (every lane
 # is an independent recurrence), so when more than one local device is
-# available the padded lane axis can be split across them.  Off by
-# default.  While it is on, lane batches pad to a multiple of the device
-# count, so every batched dispatch splits over every device; with one
-# device the dispatch is the unsharded one, bitwise.
-_LANE_SHARDING = False
-
-
-def set_lane_sharding(enabled: bool) -> bool:
-    """Enable/disable device-sharded sweep dispatch; returns the
-    previous setting (for restoration)."""
-    global _LANE_SHARDING
-    prev = _LANE_SHARDING
-    _LANE_SHARDING = bool(enabled)
-    return prev
-
-
-@contextlib.contextmanager
-def lane_sharding(enabled: bool = True):
-    """Scoped ``set_lane_sharding`` — the serving layer wraps a wave's
-    family dispatches in this context when ``shard=True``."""
-    prev = set_lane_sharding(enabled)
-    try:
-        yield
-    finally:
-        set_lane_sharding(prev)
-
-
+# available the padded lane axis can be split across them.  It is an
+# argument of the sweep (``shard=``), off by default.  A sharded sweep
+# pads its lane batches to a multiple of the device count, so every
+# batched dispatch splits over every device; with one device the
+# dispatch is the unsharded one, bitwise.
 def shard_device_count() -> int:
     """How many local devices a sharded dispatch would split over."""
     return len(jax.devices())
 
 
-def _lane_device_count() -> int:
-    """Devices a batched dispatch splits its lanes over right now."""
-    return shard_device_count() if _LANE_SHARDING else 1
-
-
-def _shard_lanes(n_lanes: int, *trees):
+def _shard_lanes(shard: bool, n_lanes: int, *trees):
     """Place ``(B,)``-leading pytrees across local devices along the
-    lane axis.  Returns ``(trees, sharded)``; identity (and False) when
-    sharding is off or only one device exists.  ``_pad_lanes`` sized
-    the batch, so a lane count that does not divide the device count is
-    a bug and raises."""
-    if not _LANE_SHARDING:
+    lane axis.  Returns ``(trees, sharded)``; identity (and False) unless
+    ``shard`` is set and more than one device exists.  ``_pad_lanes``
+    sized the batch, so a lane count that does not divide the device
+    count is a bug and raises."""
+    if not shard:
         return trees, False
     devs = jax.devices()
     if len(devs) <= 1:
@@ -800,13 +773,14 @@ def _stack_params(prm_list: Sequence[FastSimParams],
         for n in _PARAM_FIELDS})
 
 
-def _pad_lanes(idxs: List[int]) -> List[int]:
+def _pad_lanes(idxs: List[int], shard: bool) -> List[int]:
     """Pad a lane batch to a power of two (so repeat sweeps of any size
-    reuse the compile cache), rounded up to a multiple of the devices a
-    sharded dispatch splits it over."""
+    reuse the compile cache); a ``shard``-ed batch is rounded up to a
+    multiple of the local devices it splits over."""
     pad = 1 << (len(idxs) - 1).bit_length()
-    n_dev = _lane_device_count()
-    pad = -(-pad // n_dev) * n_dev
+    if shard:
+        n_dev = shard_device_count()
+        pad = -(-pad // n_dev) * n_dev
     return idxs + [idxs[-1]] * (pad - len(idxs))
 
 
@@ -836,7 +810,8 @@ Params = Union[FastSimParams, Sequence[FastSimParams]]
 
 
 def sweep_hpl(configs: Configs, params: Params, *,
-              bucket: Optional[Tuple[int, int, int]] = None) -> List[dict]:
+              bucket: Optional[Tuple[int, int, int]] = None,
+              shard: bool = False) -> List[dict]:
     """Run a scenario sweep in as few compiled programs as possible.
 
     ``configs`` and ``params`` are zipped; a single ``HPLConfig`` or
@@ -852,8 +827,14 @@ def sweep_hpl(configs: Configs, params: Params, *,
     ``bucket=(n_panels_max, P_max, Q_max)`` forces every scenario into
     ONE padded shape bucket: the whole sweep runs as a single compiled
     vmapped program regardless of geometry mix (the TOP500 fleet path —
-    one compile for a whole list).  Each component is rounded up to a
-    cache-friendly bucket size; a config that doesn't fit raises.
+    one compile for a whole list).  One-row and one-column grids run in
+    that bucket with the side cut to 1, a call of their own, so every
+    answer is the one its own bucket gives.  Each component is rounded
+    up to a cache-friendly bucket size; a config that doesn't fit raises.
+
+    ``shard=True`` splits each batched call's padded lane axis across
+    the local devices (lane batches pad to a multiple of the device
+    count); with one device it is the unsharded call, bitwise.
     """
     cfg_list = [configs] if isinstance(configs, HPLConfig) else list(configs)
     prm_list = [params] if isinstance(params, FastSimParams) else list(params)
@@ -872,8 +853,10 @@ def sweep_hpl(configs: Configs, params: Params, *,
         with (Timer(span="fastsim.prepare") if m.enabled
               else contextlib.nullcontext()) as prep:
             nodes = [_node_args(c, p) for c, p in zip(cfg_list, prm_list)]
-            plan = (_plan(cfg_list, prm_list, nodes) if bucket is None
-                    else _plan_forced(cfg_list, prm_list, nodes, bucket))
+            plan = (_plan(cfg_list, prm_list, nodes, shard=shard)
+                    if bucket is None else
+                    _plan_forced(cfg_list, prm_list, nodes, bucket,
+                                 shard=shard))
         for fn, args, idxs, key, lanes, sharded, aware in plan:
             out = _call(fn, args, key, len(idxs), lanes, sharded,
                         node_aware=aware, rows=key[1])
@@ -885,12 +868,13 @@ def sweep_hpl(configs: Configs, params: Params, *,
 
 def _plan(cfg_list: Sequence[HPLConfig],
           prm_list: Sequence[FastSimParams],
-          nodes: Sequence[tuple]) -> List[tuple]:
+          nodes: Sequence[tuple], *, shard: bool = False) -> List[tuple]:
     """The host's sweep assembly: the compiled calls ``(fn, args,
     scenario indices, shape bucket, lanes, sharded, node-aware)`` that
     answer every scenario — one params-mode call per shared geometry and
     node block, one batch-mode call per shape bucket of the rest, single
-    calls for loners.  ``nodes`` holds each scenario's ``_node_args``."""
+    calls for loners.  ``nodes`` holds each scenario's ``_node_args``;
+    ``shard`` splits the batched calls' lanes across local devices."""
     by_cfg: Dict[tuple, List[int]] = {}
     for idx, cfg in enumerate(cfg_list):
         by_cfg.setdefault((cfg.N, cfg.nb, cfg.P, cfg.Q, nodes[idx][:2]),
@@ -903,11 +887,12 @@ def _plan(cfg_list: Sequence[HPLConfig],
         if len(idxs) == 1:
             mixed.setdefault(key, []).append(idxs[0])
             continue
-        lanes = _pad_lanes(idxs)
+        lanes = _pad_lanes(idxs, shard)
         per_lane = [_stack_params(prm_list, lanes)]
         if block:    # the intra-node latency is a lane's, as the params
             per_lane.append(np.asarray([nodes[i][2] for i in lanes]))
-        (stacked, *alpha0), sharded = _shard_lanes(len(lanes), *per_lane)
+        (stacked, *alpha0), sharded = _shard_lanes(shard, len(lanes),
+                                                   *per_lane)
         args = (np.int64(N), np.int64(nb), np.int64(P), np.int64(Q),
                 stacked) + block + tuple(alpha0)
         plan.append((_compiled(*key, "params", bool(block)), args,
@@ -919,20 +904,22 @@ def _plan(cfg_list: Sequence[HPLConfig],
                          _single_args(cfg_list[i], prm_list[i], nodes[i]),
                          idxs, key, 1, False, bool(nodes[i])))
         else:
-            plan.append(_batch_call(cfg_list, prm_list, nodes, idxs, key))
+            plan.append(_batch_call(cfg_list, prm_list, nodes, idxs, key,
+                                    shard=shard))
     return plan
 
 
 def _batch_call(cfg_list: Sequence[HPLConfig],
                 prm_list: Sequence[FastSimParams],
                 nodes: Sequence[tuple],
-                idxs: List[int], key: Tuple[int, int, int]) -> tuple:
+                idxs: List[int], key: Tuple[int, int, int], *,
+                shard: bool = False) -> tuple:
     """One batch-mode call over scenarios ``idxs`` in shape bucket
     ``key``: geometry and params both ride the padded lane axis, and the
     node arguments too where any lane has them (a node-blind lane rides
     the node-aware program as the block (1, 1), which prices every hop
     and swap round as the node-blind program does, bitwise)."""
-    lanes = _pad_lanes(idxs)
+    lanes = _pad_lanes(idxs, shard)
     geom = np.asarray([[cfg_list[i].N, cfg_list[i].nb,
                         cfg_list[i].P, cfg_list[i].Q]
                        for i in lanes], np.int64)
@@ -944,7 +931,7 @@ def _batch_call(cfg_list: Sequence[HPLConfig],
         cols += [np.asarray([n[0] for n in lane_nodes], np.int64),
                  np.asarray([n[1] for n in lane_nodes], np.int64),
                  np.asarray([n[2] for n in lane_nodes], np.float64)]
-    args, sharded = _shard_lanes(len(lanes), *cols)
+    args, sharded = _shard_lanes(shard, len(lanes), *cols)
     return (_compiled(*key, "batch", aware), args, idxs, key, len(lanes),
             sharded, aware)
 
@@ -952,18 +939,26 @@ def _batch_call(cfg_list: Sequence[HPLConfig],
 def _plan_forced(cfg_list: Sequence[HPLConfig],
                  prm_list: Sequence[FastSimParams],
                  nodes: Sequence[tuple],
-                 bucket: Tuple[int, int, int]) -> List[tuple]:
-    """One batch-mode call for the whole sweep under a shared
-    (rounded-up) bucket — exactly one traced program per distinct
-    forced bucket, however many geometries are mixed in."""
+                 bucket: Tuple[int, int, int], *,
+                 shard: bool = False) -> List[tuple]:
+    """Batch-mode calls for the whole sweep under a shared (rounded-up)
+    bucket — one traced program per distinct forced bucket, however many
+    two-dimensional geometries are mixed in.  The recurrence reads
+    ``P_max > 1`` as ``P > 1`` and ``Q_max > 1`` as ``Q > 1`` (true of a
+    config's own bucket, since bucket(1) == 1), so one-row and
+    one-column grids run in the bucket with that side cut to 1: a call
+    of their own, answering as their own bucket does."""
     n_panels_max, P_max, Q_max = (_bucket(b) for b in bucket)
-    for cfg in cfg_list:
+    calls: Dict[Tuple[int, int], List[int]] = {}
+    for idx, cfg in enumerate(cfg_list):
         if (cfg.n_panels > n_panels_max or cfg.P > P_max
                 or cfg.Q > Q_max):
             raise ValueError(
                 f"sweep_hpl: config (N={cfg.N}, nb={cfg.nb}, P={cfg.P}, "
                 f"Q={cfg.Q}) exceeds forced bucket "
                 f"({n_panels_max}, {P_max}, {Q_max})")
-    return [_batch_call(cfg_list, prm_list, nodes,
-                        list(range(len(cfg_list))),
-                        (n_panels_max, P_max, Q_max))]
+        calls.setdefault((P_max if cfg.P > 1 else 1,
+                          Q_max if cfg.Q > 1 else 1), []).append(idx)
+    return [_batch_call(cfg_list, prm_list, nodes, idxs,
+                        (n_panels_max, P, Q), shard=shard)
+            for (P, Q), idxs in calls.items()]

@@ -1,10 +1,8 @@
 from .cache import ResultCache, as_result_cache, request_key
 from .engine import ServeEngine, Request
-from .predict import (HPLPredictionService, PredictRequest,
-                      PredictionService, WorkloadRequest, predict_top500,
+from .predict import (PredictionService, WorkloadRequest, predict_top500,
                       warm)
 
-__all__ = ["ServeEngine", "Request", "HPLPredictionService",
-           "PredictRequest", "PredictionService", "WorkloadRequest",
+__all__ = ["ServeEngine", "Request", "PredictionService", "WorkloadRequest",
            "ResultCache", "as_result_cache", "request_key",
            "predict_top500", "warm"]

@@ -1,26 +1,22 @@
-"""Batch prediction services: simulation-as-a-service endpoints.
+"""Batch prediction service: the simulation-as-a-service endpoint.
 
-``PredictionService`` is the workload-generic front end: requests name a
+``PredictionService`` is the one front end: requests name a
 ``(workload, platform)`` pair (registry names, specs, or instances) and
 ``flush`` drains the queue in micro-batches, one batched sweep per
 workload family per wave (``FastModel.sweep_models``) — HPL requests
 share ``sweep_hpl`` programs, transformer requests share ``sweep_step``
-programs, and a mixed burst costs one dispatch per family.
+programs, and a mixed burst costs one dispatch per family.  A burst of
+thousands of requests costs a handful of compiles (shape-bucket LRU
+cache) — the serving answer to the paper's 4.8-hour-per-scenario
+SystemC baseline.
 
-``HPLPredictionService`` is the original HPL-specialized endpoint, kept
-as the back-compat surface for cfg/params-level requests (an
-``HPLConfig`` plus a ``FastSimParams`` what-if).  A burst of thousands
-of requests costs a handful of compiles (shape-bucket LRU cache) and one
-vmapped dispatch per (bucket, wave) — the serving answer to the paper's
-4.8-hour-per-scenario SystemC baseline.
+``WorkloadRequest(rid=1, workload="hpl", platform="frontera")`` serves
+that machine's published HPL run from its spec (DES-calibrated fastsim
+params included); ``params={"N": ..., "nb": ..., "P": ..., "Q": ...}``
+overrides the run geometry.  Parameter-level what-ifs over one
+``HPLConfig`` go straight to ``fastsim.sweep_hpl``.
 
-Requests can name a registered platform instead of carrying explicit
-params: ``PredictRequest(rid=1, platform="frontera")`` serves that
-machine's published HPL run from its spec (DES-calibrated fastsim
-params included), so the endpoint can predict any registry machine by
-name.
-
-Both services accept ``breakdown=True``: a traced DES of the same
+Requests accept ``breakdown=True``: a traced DES of the same
 scenario runs and ``result["breakdown"]`` carries per-phase times,
 compute/comm/idle fractions and the critical path (see ``repro.trace``).
 The DES costs real wall time per rank, so breakdown requests are capped
@@ -65,13 +61,16 @@ Production throughput (all opt-in; DESIGN.md §20):
     cached.
   * ``PredictionService(shard=True)`` splits each family sweep's padded
     lane axis across local devices (the batch pads to a multiple of the
-    device count); with one device it is the exact unsharded code path.
+    device count); the setting is passed down to the sweep
+    (``sweep_models(..., shard=)``), never held in a module global, so
+    services in one process with different settings cannot mix them.
+    With one device it is the exact unsharded code path.
   * ``svc.warm(workloads, platforms, count=...)`` (or ``python -m
     repro.serve warm``) precompiles the sweep buckets a traffic mix
     will need, so the first real wave pays zero compiles — verified by
     the §18 compile hit/miss counters.
 
-Observability (``repro.obs``, DESIGN.md §18): both services carry a
+Observability (``repro.obs``, DESIGN.md §18): the service carries a
 ``MetricsRegistry`` (``svc.metrics``; pass ``metrics=NULL_METRICS`` to
 switch it off, or share one registry across services/replicas — they
 merge).  Counters back every hardening path (retries, deadline
@@ -99,27 +98,13 @@ from __future__ import annotations
 import dataclasses
 import time
 import weakref
-from typing import Any, Dict, List, Mapping, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence
 
 import jax
 
-from repro.core.apps.hpl import HPLConfig
 from repro.core.engine import SimWallDeadline
-from repro.core.fastsim import (FastSimParams, lane_sharding, sweep_hpl,
-                                trace_count)
 from repro.obs import COUNT_BUCKETS, MetricsRegistry, manifest_line
 from repro.serve.cache import as_result_cache, copy_payload, request_key
-
-
-@dataclasses.dataclass
-class PredictRequest:
-    rid: int
-    cfg: Optional[HPLConfig] = None
-    params: Optional[FastSimParams] = None
-    platform: Optional[str] = None       # registry name; fills cfg/params
-    breakdown: bool = False              # attach a DES phase breakdown
-    result: Optional[dict] = None
-    _t_submit: Optional[float] = dataclasses.field(default=None, repr=False)
 
 
 @dataclasses.dataclass
@@ -336,10 +321,7 @@ class PredictionService:
         with self.metrics.timer("serve.dispatch_s"):
             for attempt in range(self.retries + 1):
                 try:
-                    if self.shard:
-                        with lane_sharding(True):
-                            return model_cls.sweep_models(models)
-                    return model_cls.sweep_models(models)
+                    return model_cls.sweep_models(models, shard=self.shard)
                 except self.TRANSIENT as exc:
                     if (attempt == self.retries
                             or isinstance(exc, jax.errors.JaxRuntimeError)):
@@ -664,168 +646,6 @@ class PredictionService:
         return manifest_line("serve_run", meta=base, metrics=self.metrics)
 
 
-class HPLPredictionService:
-    """Micro-batching front end over the batched sweep engine — the
-    HPL-specialized back-compat surface (cfg/params-level requests);
-    new call sites should prefer the workload-generic
-    ``PredictionService``."""
-
-    def __init__(self, max_batch: int = 256, max_des_ranks: int = 1024,
-                 metrics: Any = None):
-        self.max_batch = max_batch
-        self.max_des_ranks = max_des_ranks
-        self._queue: List[PredictRequest] = []
-        self.stats = {"requests": 0, "batches": 0, "scenarios": 0,
-                      "traces": 0, "des_breakdowns": 0}
-        #: same metric names as PredictionService (serve.requests,
-        #: serve.batches, serve.scenarios, serve.sweeps, ...), so the
-        #: two endpoints are drop-in equivalents on a dashboard
-        self.metrics = MetricsRegistry() if metrics is None else metrics
-
-    def _resolve(self, req: PredictRequest) -> None:
-        if req.params is None or req.cfg is None:
-            if req.platform is None:
-                raise ValueError(
-                    f"request {req.rid}: needs (cfg, params) or a "
-                    "platform name")
-            from repro.platforms import get_platform
-            plat = get_platform(req.platform)
-            if req.params is None:
-                req.params = plat.fastsim()
-            if req.cfg is None:
-                req.cfg = plat.hpl_config()
-        if req.breakdown:
-            if req.platform is None:
-                raise ValueError(
-                    f"request {req.rid}: breakdown=True needs a platform "
-                    "name (the DES is built from the spec)")
-            if req.cfg.n_ranks > self.max_des_ranks:
-                raise ValueError(
-                    f"request {req.rid}: breakdown DES at "
-                    f"{req.cfg.n_ranks} ranks exceeds max_des_ranks="
-                    f"{self.max_des_ranks}; pass a scaled-down cfg")
-
-    def submit(self, req: PredictRequest) -> None:
-        self._resolve(req)
-        self.stats["requests"] += 1
-        self._queue.append(req)
-        if self.metrics.enabled:
-            req._t_submit = time.perf_counter()
-            self.metrics.counter("serve.requests").inc()
-            self.metrics.gauge("serve.queue_depth").set(len(self._queue))
-
-    def _des_breakdown(self, req: PredictRequest) -> dict:
-        """Traced DES of the request scenario -> phase/category report."""
-        from repro.core.apps.hpl import HPLSim
-        from repro.platforms import get_platform
-        sim = HPLSim(req.cfg, get_platform(req.platform), trace=True)
-        if self.metrics.enabled:
-            sim.engine.metrics = self.metrics
-            with self.metrics.timer("serve.des_wall_s"):
-                res = sim.run()
-        else:
-            res = sim.run()
-        out = res.trace.summary()
-        out["des_time_s"] = res.time_s
-        out["des_gflops"] = res.gflops
-        self.stats["des_breakdowns"] += 1
-        self.metrics.counter("serve.des_breakdowns").inc()
-        return out
-
-    def flush(self) -> Dict[int, dict]:
-        """Drain the queue in waves of up to ``max_batch`` scenarios.
-
-        Each wave is one ``sweep_hpl`` call: scenarios sharing a shape
-        bucket run as a single compiled vmapped program.  Returns
-        {rid: result-dict} for everything served.
-        """
-        results: Dict[int, dict] = {}
-        m = self.metrics
-        t0 = trace_count()
-        while self._queue:
-            wave = self._queue[:self.max_batch]
-            del self._queue[:self.max_batch]
-            if m.enabled:
-                m.histogram("serve.wave_size", COUNT_BUCKETS).observe(
-                    len(wave))
-                m.gauge("serve.queue_depth").set(len(self._queue))
-            res = sweep_hpl([r.cfg for r in wave],
-                            [r.params for r in wave])
-            m.counter("serve.sweeps").inc()   # one sweep_hpl per wave
-            for req, out in zip(wave, res):
-                if req.breakdown:
-                    out = dict(out)
-                    out["breakdown"] = self._des_breakdown(req)
-                req.result = out
-                results[req.rid] = out
-                if m.enabled and req._t_submit is not None:
-                    m.histogram("serve.request_latency_s").observe(
-                        time.perf_counter() - req._t_submit)
-            self.stats["batches"] += 1
-            self.stats["scenarios"] += len(wave)
-            if m.enabled:
-                m.counter("serve.batches").inc()
-                m.counter("serve.scenarios").inc(len(wave))
-        self.stats["traces"] += trace_count() - t0
-        return results
-
-    def predict_batch(self, scenarios: Sequence[PredictRequest]
-                      ) -> Dict[int, dict]:
-        """Submit + flush in one call — the RPC-handler entry point.
-
-        All-or-nothing on resolution: every request is resolved before
-        any is enqueued, so one bad request (unknown platform name
-        mid-batch, missing cfg) rejects the whole call and leaves the
-        queue exactly as it was.  An empty batch returns {} without
-        dispatching anything.
-        """
-        scenarios = list(scenarios)
-        for req in scenarios:
-            self._resolve(req)
-        if not scenarios:
-            return {}
-        for req in scenarios:
-            self.submit(req)        # _resolve is idempotent
-        return self.flush()
-
-    def predict_platforms(self, names: Sequence[str],
-                          cfg: Optional[HPLConfig] = None,
-                          ) -> Mapping[str, dict]:
-        """Predict a batch of registry machines by name (their published
-        HPL runs, or a shared ``cfg`` override) in one sweep."""
-        reqs = [PredictRequest(rid=i, cfg=cfg, platform=name)
-                for i, name in enumerate(names)]
-        out = self.predict_batch(reqs)
-        return {name: out[i] for i, name in enumerate(names)}
-
-    def predict_top500(self, csv_path, **kw) -> dict:
-        """Serve a whole TOP500 list export: ranked predicted-vs-
-        published Rmax report as a JSON-safe dict (delegates to
-        ``repro.top500.predict_top500``; same keywords)."""
-        report = predict_top500(csv_path, metrics=self.metrics, **kw)
-        self.stats["requests"] += len(report.entries)
-        self.stats["scenarios"] += len(report.entries)
-        self.stats["batches"] += 1
-        if self.metrics.enabled:
-            self.metrics.counter("serve.requests").inc(len(report.entries))
-            self.metrics.counter("serve.scenarios").inc(len(report.entries))
-            self.metrics.counter("serve.batches").inc()
-        return report.to_dict()
-
-    # ------------------------------------------------------ observability
-    def prometheus(self) -> str:
-        """The service's metrics in Prometheus text exposition format."""
-        return self.metrics.to_prometheus()
-
-    def manifest(self, **meta) -> str:
-        """One NDJSON run-manifest line (same shape as
-        ``PredictionService.manifest``)."""
-        base = {"service": type(self).__name__,
-                "max_batch": self.max_batch, "stats": dict(self.stats)}
-        base.update(meta)
-        return manifest_line("serve_run", meta=base, metrics=self.metrics)
-
-
 def warm(workloads: Any = ("hpl",), platforms: Any = (), *,
          count: int = 1, prime_cache: bool = False,
          service: Optional[PredictionService] = None,
@@ -850,7 +670,7 @@ def predict_top500(csv_path, *, namespace: Optional[str] = None,
 
     ``namespace="top500"`` additionally registers every inferred spec as
     ``top500/<name>`` so individual machines can then be served by name
-    through ``PredictRequest(platform=...)``; re-ingesting the same list
+    through ``WorkloadRequest(platform=...)``; re-ingesting the same list
     needs ``overwrite=True`` (forwarded to ``bulk_register``).  Remaining
     keywords reach ``repro.top500.predict_fleet`` (``tuning=``,
     ``calibrate=``, ``infer_kw=``).

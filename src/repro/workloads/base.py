@@ -119,8 +119,10 @@ class FastModel(abc.ABC):
 
     @classmethod
     @abc.abstractmethod
-    def sweep_models(cls, models: Sequence["FastModel"]) -> List[dict]:
-        """Batch heterogeneous scenarios of this family in one sweep."""
+    def sweep_models(cls, models: Sequence["FastModel"], *,
+                     shard: bool = False) -> List[dict]:
+        """Batch heterogeneous scenarios of this family in one sweep;
+        ``shard`` splits its lanes across local devices."""
 
 
 class Workload(abc.ABC):

@@ -26,12 +26,14 @@ class HPLFastModel(FastModel):
     params: object                     # FastSimParams
 
     @classmethod
-    def sweep_models(cls, models: Sequence["HPLFastModel"]) -> List[dict]:
+    def sweep_models(cls, models: Sequence["HPLFastModel"], *,
+                     shard: bool = False) -> List[dict]:
         """One compiled program per wave: scenarios sharing a shape
         bucket take ``sweep_hpl``'s grouped fast path; a wave that
         mixes buckets (a campaign grid over heterogeneous platforms)
         is forced into one shared bucket instead — the TOP500 fleet
-        trick, so the family costs one dispatch either way."""
+        trick, so the family costs one dispatch either way (one more
+        for its one-row or one-column grids, see ``sweep_hpl``)."""
         from repro.core.fastsim import bucket_key, sweep_hpl
         cfgs = [m.cfg for m in models]
         prms = [m.params for m in models]
@@ -39,8 +41,8 @@ class HPLFastModel(FastModel):
             bucket = (max(c.n_panels for c in cfgs),
                       max(c.P for c in cfgs),
                       max(c.Q for c in cfgs))
-            return sweep_hpl(cfgs, prms, bucket=bucket)
-        return sweep_hpl(cfgs, prms)
+            return sweep_hpl(cfgs, prms, bucket=bucket, shard=shard)
+        return sweep_hpl(cfgs, prms, shard=shard)
 
 
 @register_workload

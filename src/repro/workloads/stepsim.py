@@ -145,13 +145,16 @@ def _result(p: StepParams, t: float) -> Dict:
             "mfu": flops / max(t, 1e-30) / p.peak_flops}
 
 
-def sweep_step(params_list: Sequence[StepParams]) -> List[Dict]:
+def sweep_step(params_list: Sequence[StepParams], *,
+               shard: bool = False) -> List[Dict]:
     """Run a step-scenario sweep as one compiled batched program.
 
     The batch is padded to a power of two so repeat sweeps of any size
     reuse the compile cache; results come back in input order as dicts
     with ``time_s``/``step_s``/``mfu`` (model-level fields like
-    tokens/s are layered on by ``TransformerWorkload``).
+    tokens/s are layered on by ``TransformerWorkload``).  ``shard=True``
+    splits the padded lane axis across local devices, as in
+    ``sweep_hpl``.
     """
     prm_list = [_f64_step_params(p) for p in params_list]
     if not prm_list:
@@ -161,9 +164,9 @@ def sweep_step(params_list: Sequence[StepParams]) -> List[Dict]:
     with jax.enable_x64(True):
         with (Timer(span="stepsim.prepare") if m.enabled
               else contextlib.nullcontext()) as prep:
-            lanes = _pad_lanes(list(range(len(prm_list))))
+            lanes = _pad_lanes(list(range(len(prm_list))), shard)
             (stacked,), sharded = _shard_lanes(
-                len(lanes), _stack_step_params(prm_list, lanes))
+                shard, len(lanes), _stack_step_params(prm_list, lanes))
         # fastsim's dispatch taxonomy under the stepsim.* prefix, one
         # shared "step" bucket (the step core is shape-monomorphic)
         out = _call(_compiled(), (stacked,), ("step",), len(prm_list),

@@ -56,9 +56,10 @@ class StepFastModel(FastModel):
     tokens_per_step: float = 0.0       # global tokens per optimizer step
 
     @classmethod
-    def sweep_models(cls, models: Sequence["StepFastModel"]) -> List[dict]:
+    def sweep_models(cls, models: Sequence["StepFastModel"], *,
+                     shard: bool = False) -> List[dict]:
         from .stepsim import sweep_step
-        res = sweep_step([m.params for m in models])
+        res = sweep_step([m.params for m in models], shard=shard)
         for m, r in zip(models, res):
             if m.tokens_per_step:
                 r["tokens_per_s"] = m.tokens_per_step / r["time_s"]

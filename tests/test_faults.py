@@ -341,11 +341,11 @@ def test_service_retries_transient_backend_errors():
     orig = HPLFastModel.sweep_models.__func__
     calls = {"n": 0}
 
-    def flaky(cls, models):
+    def flaky(cls, models, *, shard=False):
         calls["n"] += 1
         if calls["n"] < 3:
             raise RuntimeError("transient backend glitch")
-        return orig(cls, models)
+        return orig(cls, models, shard=shard)
 
     HPLFastModel.sweep_models = classmethod(flaky)
     try:
@@ -378,7 +378,7 @@ def test_service_does_not_retry_xla_errors():
     orig = HPLFastModel.sweep_models.__func__
     calls = {"n": 0}
 
-    def oom(cls, models):
+    def oom(cls, models, *, shard=False):
         calls["n"] += 1
         raise jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: out of HBM")
 

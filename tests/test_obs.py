@@ -340,11 +340,11 @@ def test_acceptance_wave_retry_fallback_isolation_all_visible():
     orig = HPLFastModel.sweep_models.__func__
     state = {"n": 0}
 
-    def flaky(cls, models):
+    def flaky(cls, models, *, shard=False):
         state["n"] += 1
         if state["n"] == 1:
             raise RuntimeError("transient hiccup")
-        return orig(cls, models)
+        return orig(cls, models, shard=shard)
 
     HPLFastModel.sweep_models = classmethod(flaky)
     try:
@@ -407,7 +407,7 @@ def test_dispatch_failure_stamps_wave_and_keeps_queue_clean():
     svc = PredictionService(retries=0)
     orig = HPLFastModel.sweep_models.__func__
 
-    def broken(cls, models):
+    def broken(cls, models, *, shard=False):
         raise RuntimeError("backend down")
 
     reqs = [WorkloadRequest(rid=0, workload="hpl", platform="bdw-local",
@@ -434,28 +434,22 @@ def test_dispatch_failure_stamps_wave_and_keeps_queue_clean():
     assert out[9]["time_s"] > 0
 
 
-def test_hpl_service_metric_parity():
-    # Satellite 2: the back-compat HPL endpoint reports through the
-    # same metric names, so equivalent traffic gives equal counters.
-    from repro.serve import (HPLPredictionService, PredictRequest,
-                             PredictionService, WorkloadRequest)
+def test_hpl_by_name_wave_metric_counts():
+    # two HPL requests by platform name are one wave of one sweep
+    from repro.serve import PredictionService, WorkloadRequest
     names = ["frontera", "bdw-local"]
-    svc_g, svc_h = PredictionService(), HPLPredictionService()
-    svc_g.predict_batch([
+    svc = PredictionService()
+    svc.predict_batch([
         WorkloadRequest(rid=i, workload="hpl", platform=n)
         for i, n in enumerate(names)])
-    svc_h.predict_batch([
-        PredictRequest(rid=i, platform=n) for i, n in enumerate(names)])
-    cg = svc_g.metrics.snapshot()["counters"]
-    ch = svc_h.metrics.snapshot()["counters"]
-    for key in ("serve.requests", "serve.batches", "serve.scenarios",
-                "serve.sweeps"):
-        assert cg[key] == ch[key], key
-    hg = svc_g.metrics.snapshot()["histograms"]
-    hh = svc_h.metrics.snapshot()["histograms"]
-    assert hg["serve.request_latency_s"]["count"] == 2
-    assert hh["serve.request_latency_s"]["count"] == 2
-    assert hg["serve.wave_size"]["sum"] == hh["serve.wave_size"]["sum"]
+    snap = svc.metrics.snapshot()
+    c = snap["counters"]
+    assert [c[k] for k in ("serve.requests", "serve.batches",
+                           "serve.scenarios", "serve.sweeps")] == \
+        [2.0, 1.0, 2.0, 1.0]
+    h = snap["histograms"]
+    assert h["serve.request_latency_s"]["count"] == 2
+    assert h["serve.wave_size"]["sum"] == 2.0
 
 
 def test_service_registries_merge_across_replicas():

@@ -160,18 +160,21 @@ def test_bridge_frontera_reproduces_table2_within_5pct():
 # ------------------------------------------------------ serving by name
 
 def test_service_serves_platform_names():
-    from repro.serve import HPLPredictionService, PredictRequest
-    svc = HPLPredictionService()
-    cfg = HPLConfig(N=2048, nb=128, P=2, Q=4)
-    out = svc.predict_platforms(["frontera", "pupmaya", "tpu-v5e-pod"],
-                                cfg=cfg)
-    assert set(out) == {"frontera", "pupmaya", "tpu-v5e-pod"}
-    for name in out:
-        expect = simulate_hpl_fast(cfg, get_platform(name).fastsim())
-        assert out[name]["time_s"] == pytest.approx(expect["time_s"],
-                                                    rel=1e-6)
-    # a platform-name request with no cfg serves the published run shape
-    req = PredictRequest(rid=7, platform="bdw-local")
+    from repro.serve import PredictionService, WorkloadRequest
+    svc = PredictionService()
+    geom = {"N": 2048, "nb": 128, "P": 2, "Q": 4}
+    names = ["frontera", "pupmaya", "tpu-v5e-pod"]
+    out = svc.predict_batch([
+        WorkloadRequest(rid=i, workload="hpl", platform=name, params=geom)
+        for i, name in enumerate(names)])
+    assert set(out) == {0, 1, 2}
+    for i, name in enumerate(names):
+        plat = get_platform(name)
+        expect = simulate_hpl_fast(plat.hpl_config(**geom), plat.fastsim())
+        assert out[i]["time_s"] == pytest.approx(expect["time_s"],
+                                                 rel=1e-6)
+    # a platform-name request with no overrides serves the published run
+    req = WorkloadRequest(rid=7, workload="hpl", platform="bdw-local")
     res = svc.predict_batch([req])
     plat = get_platform("bdw-local")
     expect = simulate_hpl_fast(plat.hpl_config(), plat.fastsim())
@@ -179,8 +182,9 @@ def test_service_serves_platform_names():
 
 
 def test_service_rejects_unresolvable_request():
-    from repro.serve import HPLPredictionService, PredictRequest
-    svc = HPLPredictionService()
-    with pytest.raises(ValueError, match="platform"):
-        svc.submit(PredictRequest(rid=0, cfg=HPLConfig(N=512, nb=128,
-                                                       P=1, Q=1)))
+    from repro.serve import PredictionService, WorkloadRequest
+    svc = PredictionService()
+    with pytest.raises(ValueError, match="needs a platform"):
+        svc.submit(WorkloadRequest(rid=0, workload="hpl", params={
+            "N": 512, "nb": 128, "P": 1, "Q": 1}))
+    assert not svc._queue

@@ -285,13 +285,26 @@ def test_shard_single_device_is_bitwise_identical():
 
 def test_shard_lanes_fallback_is_identity():
     import numpy as np
-    from repro.core.fastsim import _shard_lanes, lane_sharding
+    from repro.core.fastsim import _shard_lanes
     x = np.arange(8.0)
-    trees, sharded = _shard_lanes(8, x)           # sharding off
+    trees, sharded = _shard_lanes(False, 8, x)    # sharding off
     assert trees[0] is x and not sharded
-    with lane_sharding(True):                     # on, but 1 device
-        trees, sharded = _shard_lanes(8, x)
-        assert not sharded
+    trees, sharded = _shard_lanes(True, 8, x)     # on, but 1 device
+    assert trees[0] is x and not sharded
+
+
+@pytest.mark.parametrize("n, shard, n_dev, padded", [
+    (1, False, 4, 1), (3, False, 4, 4), (5, False, 3, 8), (2, True, 4, 4),
+    (3, True, 4, 4), (5, True, 4, 8), (1, True, 3, 3), (5, True, 3, 9)])
+def test_pad_lanes_rounds_to_the_devices_when_sharded(monkeypatch, n,
+                                                      shard, n_dev,
+                                                      padded):
+    from repro.core import fastsim
+    monkeypatch.setattr(fastsim, "shard_device_count", lambda: n_dev)
+    lanes = fastsim._pad_lanes(list(range(n)), shard)
+    # a power of two, rounded up to a multiple of the devices only when
+    # sharded; padding lanes repeat the last live one
+    assert lanes == list(range(n)) + [n - 1] * (padded - n)
 
 
 def test_forced_multi_device_shard_is_bitwise_identical():
@@ -312,6 +325,40 @@ def test_forced_multi_device_shard_is_bitwise_identical():
         assert shard == base, (shard, base)
         c = svc.metrics.snapshot()["counters"]
         assert c.get("fastsim.sharded_dispatches", 0) >= 1, c
+
+        # each service pads and places its lanes by its own setting: an
+        # unsharded service's 2-request wave, dispatched while a sharded
+        # service's dispatch is in flight in the same process, pads
+        # nothing and shards nothing; the sharded wave pads 2 -> 4 lanes
+        from repro.workloads import HPLFastModel
+        def two():
+            return [WorkloadRequest(rid=i, workload="hpl",
+                                    platform="frontera",
+                                    params={"N": 1536}) for i in range(2)]
+        plain, sharded = PredictionService(), PredictionService(shard=True)
+        orig = HPLFastModel.sweep_models.__func__
+        inner, nested = {}, []
+        def sweep_models(cls, models, *, shard=False):
+            if shard and not nested:
+                nested.append(True)
+                with global_metrics(plain.metrics):
+                    inner.update(plain.predict_batch(two()))
+            return orig(cls, models, shard=shard)
+        HPLFastModel.sweep_models = classmethod(sweep_models)
+        try:
+            with global_metrics(sharded.metrics):
+                outer = sharded.predict_batch(two())
+        finally:
+            HPLFastModel.sweep_models = classmethod(orig)
+        assert inner == outer, (inner, outer)
+        cp = plain.metrics.snapshot()["counters"]
+        cs = sharded.metrics.snapshot()["counters"]
+        assert cp["fastsim.lanes_live"] == 2, cp
+        assert cp.get("fastsim.lanes_padded", 0) == 0, cp
+        assert "fastsim.sharded_dispatches" not in cp, cp
+        assert cs["fastsim.lanes_live"] == 2, cs
+        assert cs["fastsim.lanes_padded"] == 2, cs
+        assert cs["fastsim.sharded_dispatches"] == 1, cs
         print("OK")
     """)
     env = dict(os.environ,

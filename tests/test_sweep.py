@@ -120,17 +120,21 @@ def test_whatif_grid_rows_match_singles():
 
 
 def test_prediction_service_batches_and_matches():
-    from repro.serve import HPLPredictionService, PredictRequest
-    svc = HPLPredictionService(max_batch=8)
-    reqs = [PredictRequest(rid=i, cfg=CONFIGS[i % 6],
-                           params=_params_for(i)) for i in range(12)]
+    from repro.platforms import get_platform
+    from repro.serve import PredictionService, WorkloadRequest
+    plat = get_platform("frontera")
+    svc = PredictionService(max_batch=8)
+    geoms = [{k: getattr(CONFIGS[i % 6], k) for k in ("N", "nb", "P", "Q")}
+             for i in range(12)]
+    reqs = [WorkloadRequest(rid=i, workload="hpl", platform="frontera",
+                            params=g) for i, g in enumerate(geoms)]
     out = svc.predict_batch(reqs)
     assert set(out) == set(range(12))
     assert svc.stats["requests"] == 12
     assert svc.stats["batches"] == 2          # 12 reqs / max_batch 8
-    for req in reqs:
-        assert out[req.rid]["time_s"] == pytest.approx(
-            simulate_hpl_fast(req.cfg, req.params)["time_s"], rel=1e-6)
+    for i, g in enumerate(geoms):
+        ref = simulate_hpl_fast(plat.hpl_config(**g), plat.fastsim())
+        assert out[i]["time_s"] == pytest.approx(ref["time_s"], rel=1e-6)
 
 
 def test_gradient_flows_through_recurrence():
@@ -190,20 +194,23 @@ GOLDEN_CASES = {
 }
 
 # float.hex of each answer as the per-panel recurrence (one fori_loop
-# step per panel, everything derived in the step) computed it on CPU
+# step per panel, everything derived in the step) computed it on CPU.
+# A one-row or one-column lane of a forced bucket runs with that side
+# of the bucket cut to 1 (``_plan_forced``); its entry is its own
+# single run's answer (``simulate_hpl_fast``), bitwise
 GOLDEN = {
     "batch_mixed": [
-        "0x1.81ccea9b7cfa1p-1", "0x1.ff44d3383a8c6p-3",
-        "0x1.ad33a12f409ffp-3", "0x1.96e2bb4ba3616p-4",
+        "0x1.81ccea9b7cfa1p-1", "0x1.f9cae103d52a0p-3",
+        "0x1.a352c2c859212p-3", "0x1.7d6f6dee4c691p-4",
         "0x1.6f16a28fc15f8p-2", "0x1.71a4f43e1e9a6p-4",
     ],
     "batch_p1": [
         "0x1.1b192271a6876p-1", "0x1.639eeb6ba8dfdp-4",
-        "0x1.24cb3ca76ec65p-7",
+        "0x1.19dafaee5a446p-7",
     ],
     "batch_q1": [
         "0x1.abdfe9720d91bp-6", "0x1.25c89c913f492p-6",
-        "0x1.f4446042989bap-8",
+        "0x1.ec458e4001e86p-8",
     ],
     "params_blocks": [
         "0x1.79904ce4835aap+0", "0x1.f7564df11ea45p-1",
@@ -221,6 +228,24 @@ GOLDEN = {
 @pytest.mark.parametrize("case", sorted(GOLDEN_CASES))
 def test_block_recurrence_matches_per_panel_answers_bitwise(case):
     assert [t.hex() for t in GOLDEN_CASES[case]()] == GOLDEN[case]
+
+
+@pytest.mark.parametrize("P, Q", [(1, 4), (4, 1), (1, 1), (2, 4)])
+def test_forced_bucket_answers_as_the_grids_own(P, Q):
+    """A forced bucket changes no answer: a one-row or one-column grid
+    among two-dimensional ones reads bitwise as its own single run, as a
+    two-dimensional grid does, and the wave costs one call per kind."""
+    cfgs = [HPLConfig(N=4000, nb=128, P=P, Q=Q),
+            HPLConfig(N=3000, nb=96, P=3, Q=3)]
+    prms = [_params_for(0), _params_for(1)]
+    forced = sweep_hpl(cfgs, prms, bucket=(32, 4, 4))
+    for cfg, prm, r in zip(cfgs, prms, forced):
+        assert r["time_s"].hex() == simulate_hpl_fast(cfg, prm)[
+            "time_s"].hex(), cfg
+    calls = fastsim._plan_forced(cfgs, prms, [(), ()], (32, 4, 4))
+    assert [c[3] for c in calls] == (
+        [(32, 4, 4)] if P > 1 and Q > 1 else
+        [(32, 4 if P > 1 else 1, 4 if Q > 1 else 1), (32, 4, 4)])
 
 
 GOLDEN_GRAD_CFG = HPLConfig(N=40000, nb=128, P=2, Q=3)   # three blocks
@@ -582,7 +607,7 @@ GOLDEN_ROWS = {
                    "0x1.0428d1a22b38ap-2"],
     "batch_p16": ["0x1.806c81854b062p-3", "0x1.0f266bfc0afcep-3",
                   "0x1.148d8230736a3p-3", "0x1.6e8942f08f591p-4",
-                  "0x1.4d1ca991a0472p-5"],
+                  "0x1.4a9bc5d171ee0p-5"],    # the 1 x 4 lane, as GOLDEN
     "single_p13": ["0x1.7a1c3ee5fa550p-3"],
     "nodes_params_p12": ["0x1.b6ab72b826017p-3", "0x1.94fc69d3e5a81p-3",
                          "0x1.ad6471aa5f864p-3"],

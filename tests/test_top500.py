@@ -365,40 +365,48 @@ def test_serve_predict_top500_surfaces_skipped_and_empty(tmp_path):
         predict_top500(str(bad), tuning=SMOKE_TUNING)
 
 
-def test_service_predict_top500_method_updates_stats():
-    from repro.serve import HPLPredictionService
+def test_serve_predict_top500_counts_rows():
+    from repro.obs import MetricsRegistry
+    from repro.serve import predict_top500
     from repro.top500 import sample_list_path
-    svc = HPLPredictionService()
-    out = svc.predict_top500(sample_list_path(), tuning=SMOKE_TUNING)
+    metrics = MetricsRegistry()
+    report = predict_top500(sample_list_path(), tuning=SMOKE_TUNING,
+                            metrics=metrics)
+    out = report.to_dict()
     assert out["machines"] and out["compiles"] <= 1
-    assert svc.stats["scenarios"] >= 50
+    assert len(report.entries) >= 50
+    assert metrics.snapshot()["counters"]["fleet.rows_parsed"] >= 50
 
 
-# ------------------------- predict_platforms error paths (satellite)
+# ------------------------- serving by name: error paths (satellite)
 
-def test_predict_platforms_unknown_name_mid_batch_leaves_queue_clean():
-    from repro.core.apps.hpl import HPLConfig
-    from repro.serve import HPLPredictionService
-    svc = HPLPredictionService()
-    cfg = HPLConfig(N=1024, nb=128, P=2, Q=2)
+def _hpl_by_name(names):
+    from repro.serve import WorkloadRequest
+    return [WorkloadRequest(rid=i, workload="hpl", platform=name,
+                            params={"N": 1024, "nb": 128, "P": 2, "Q": 2})
+            for i, name in enumerate(names)]
+
+
+def test_predict_batch_unknown_name_leaves_queue_clean():
+    from repro.serve import PredictionService
+    svc = PredictionService()
     with pytest.raises(KeyError, match="no-such"):
-        svc.predict_platforms(["frontera", "no-such-machine"], cfg=cfg)
+        svc.predict_batch(_hpl_by_name(["frontera", "no-such-machine"]))
     # the bad batch enqueued nothing and counted nothing
     assert svc.stats["requests"] == 0
     assert not svc._queue
     # the service still serves a clean follow-up batch
-    out = svc.predict_platforms(["frontera", "pupmaya"], cfg=cfg)
-    assert set(out) == {"frontera", "pupmaya"}
+    out = svc.predict_batch(_hpl_by_name(["frontera", "pupmaya"]))
+    assert set(out) == {0, 1}
     assert svc.stats["requests"] == 2
     assert svc.stats["scenarios"] == 2
 
 
-def test_predict_platforms_empty_sequence_is_a_noop():
-    from repro.serve import HPLPredictionService
-    svc = HPLPredictionService()
-    assert svc.predict_platforms([]) == {}
-    assert svc.stats == {"requests": 0, "batches": 0, "scenarios": 0,
-                         "traces": 0, "des_breakdowns": 0}
+def test_predict_batch_empty_is_a_noop():
+    from repro.serve import PredictionService
+    svc = PredictionService()
+    assert svc.predict_batch([]) == {}
+    assert not any(svc.stats.values()), svc.stats
 
 
 # ----------------------- vendored edition set (campaign satellite)

@@ -218,14 +218,14 @@ def test_platform_des_trace_flag_flows_through():
 
 def test_service_breakdown_option():
     pytest.importorskip("jax")
-    from repro.serve import HPLPredictionService, PredictRequest
-    from repro.platforms import get_platform
-    svc = HPLPredictionService()
-    cfg = get_platform("bdw-local").hpl_config(N=512, nb=64, P=2, Q=2)
+    from repro.serve import PredictionService, WorkloadRequest
+    svc = PredictionService()
+    geom = {"N": 512, "nb": 64, "P": 2, "Q": 2}
     out = svc.predict_batch([
-        PredictRequest(rid=0, cfg=cfg, platform="bdw-local"),
-        PredictRequest(rid=1, cfg=cfg, platform="bdw-local",
-                       breakdown=True)])
+        WorkloadRequest(rid=0, workload="hpl", platform="bdw-local",
+                        params=geom),
+        WorkloadRequest(rid=1, workload="hpl", platform="bdw-local",
+                        params=geom, breakdown=True)])
     assert "breakdown" not in out[0]
     bd = out[1]["breakdown"]
     assert bd["makespan_s"] > 0
@@ -237,12 +237,13 @@ def test_service_breakdown_option():
 
 
 def test_service_breakdown_guards():
-    from repro.serve import HPLPredictionService, PredictRequest
-    svc = HPLPredictionService(max_des_ranks=4)
-    cfg = HPLConfig(N=512, nb=64, P=4, Q=4)
+    from repro.serve import PredictionService, WorkloadRequest
+    svc = PredictionService(max_des_ranks=4)
     with pytest.raises(ValueError, match="max_des_ranks"):
-        svc.submit(PredictRequest(rid=0, cfg=cfg, platform="bdw-local",
-                                  breakdown=True))
+        svc.submit(WorkloadRequest(
+            rid=0, workload="hpl", platform="bdw-local",
+            params={"N": 512, "nb": 64, "P": 4, "Q": 4}, breakdown=True))
+    assert not svc._queue
 
 
 # Hypothesis property tests over random geometries live in
